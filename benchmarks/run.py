@@ -12,6 +12,8 @@ import json
 import os
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 SUITES = (
     "latency",        # Fig. 4/5, Table 2
     "scaling",        # Fig. 6 strong + weak
@@ -39,6 +41,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny parameters for CI smoke runs")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         os.environ["REPRO_BENCH_SMOKE"] = "1"
     selected = args.only.split(",") if args.only else list(SUITES)

@@ -129,6 +129,9 @@ class Endpoint:
         self.completed = 0
         self.requeued = 0
         self.lost_executors = 0
+        # executors declared dead, by id, with their released block: one
+        # that beats again is taken back (see _watchdog)
+        self._lost: Dict[str, tuple] = {}
 
         if provider is None:
             provider = LocalThreadProvider(
@@ -472,6 +475,21 @@ class Endpoint:
             )
 
     def _watchdog(self) -> None:
+        # a death declared on a stall (the executor's beats were held off,
+        # not stopped) is undone once it beats again, as the Forwarder takes
+        # back an endpoint: without this a non-elastic endpoint would keep
+        # heartbeating to the fabric with no executor to run its queue
+        for eid in self.monitor.revived():
+            ex, block = self._lost.get(eid, (None, None))
+            if ex is None or not self.provider.readmit(block, ex):
+                continue  # unknown here, or the block ceiling is full
+            del self._lost[eid]
+            with self._exlock:
+                self.executors[eid] = ex
+                self._block_of[eid] = block
+            ex.resume()
+            self.monitor.resume(eid)
+            self.metrics.counter("endpoint.executors_readmitted").inc()
         for eid in self.monitor.dead():
             with self._exlock:
                 ex = self.executors.get(eid)
@@ -502,6 +520,7 @@ class Endpoint:
             with self._exlock:
                 del self.executors[eid]
                 dead_block = self._block_of.pop(eid, None)
+            self._lost[eid] = (ex, dead_block)
             if self.elastic:
                 # Replacement flows through the autoscaler: the dead block is
                 # released from the provider before a new one is requested, so
@@ -604,6 +623,8 @@ class Endpoint:
         self._alive = False
         self._manager.join(timeout=2.0)
         for ex in self._executor_list():
+            ex.shutdown()
+        for ex, _ in list(self._lost.values()):
             ex.shutdown()
         with self._exlock:
             self.executors.clear()

@@ -266,6 +266,7 @@ class Forwarder:
         self.results = ResultStore(metrics=self.metrics)
         self.ewma_alpha = ewma_alpha
         self.liveness_threshold_s = liveness_threshold_s
+        self._last_check: Optional[float] = None  # time of the last pass
         self.watchdog_interval_s = watchdog_interval_s
         self.failover = failover
         self.failovers = 0
@@ -1041,17 +1042,27 @@ class Forwarder:
         """Detect newly-dead endpoints and fail their outstanding tasks over to
         survivors. Returns the ids of endpoints declared dead this call."""
         newly_dead: List[Tuple[EndpointRecord, List[TaskEnvelope]]] = []
+        now = time.monotonic()
         with self._lock:
+            since = None if self._last_check is None else now - self._last_check
+            self._last_check = now
             for rec in self._records.values():
+                is_alive = getattr(rec.endpoint, "is_alive", None)
+                if is_alive is None:
+                    continue
                 if rec.dead:
                     # resurrection: a heartbeat-stall false positive (GIL/CPU
                     # pressure) recovers once the endpoint beats again; a
                     # killed endpoint never does (_alive stays False)
-                    is_alive = getattr(rec.endpoint, "is_alive", None)
-                    if is_alive is None or is_alive(self.liveness_threshold_s):
+                    if is_alive(self.liveness_threshold_s):
                         rec.dead = False
                     continue
-                if self._is_live(rec):
+                # a killed endpoint is dead at once; a silent one only when
+                # its heartbeat was already past the threshold at the
+                # previous pass (HeartbeatMonitor.dead says why)
+                if is_alive(None) and (
+                    since is None or is_alive(self.liveness_threshold_s + since)
+                ):
                     continue
                 rec.dead = True
                 evicted = self.sessions.evict_endpoint(rec.endpoint.endpoint_id)

@@ -58,6 +58,15 @@ class Provider(abc.ABC):
         for bid in block_ids:
             self._blocks.pop(bid, None)
 
+    def readmit(self, block_id: str, block: object) -> bool:
+        """Count a released block again: its executor beat again, so the
+        death was a false positive. False when ``max_blocks`` are in use
+        (a replacement took its place)."""
+        if len(self._blocks) >= self.spec.max_blocks:
+            return False
+        self._blocks[block_id] = block
+        return True
+
     def status(self) -> dict:
         return {"blocks": len(self._blocks), "spec": self.spec}
 

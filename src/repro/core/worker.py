@@ -117,7 +117,9 @@ def strip_traceback(exc: BaseException) -> BaseException:
 class _JaxExecutable:
     """jit-wrapped registered function; AOT-compiles on construction when a
     sample payload is available (so WarmPool timing captures the real compile
-    cost, the Table-4 'container instantiation' analogue)."""
+    cost, the Table-4 'container instantiation' analogue). A compile the
+    backend refuses fails the task here, as the first call would have; later
+    payloads of other shapes compile lazily per call."""
 
     def __init__(self, rf: "RegisteredFunction", sample_payload: Any = None):
         import jax
@@ -125,10 +127,7 @@ class _JaxExecutable:
         jit_kwargs = rf.metadata.get("jit_kwargs", {})
         self._jitted = jax.jit(rf.fn, **jit_kwargs)
         if sample_payload is not None:
-            try:
-                self._jitted.lower(sample_payload).compile()
-            except Exception:
-                pass  # shape-polymorphic usage: compile lazily per call
+            self._jitted.lower(sample_payload).compile()
 
     def __call__(self, payload: Any) -> Any:
         out = self._jitted(payload)

@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .._compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _kernel(x_ref, d_ref, s_ref, res_ref, out_ref, *, eps: float):
@@ -63,7 +62,7 @@ def fused_add_rmsnorm_pallas(
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
             jax.ShapeDtypeStruct(x2.shape, x.dtype),
         ],
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2, d2, scale)
     if pad:

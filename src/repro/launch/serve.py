@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.core import FunctionService
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.serving.engine import ServeEngine
 
@@ -33,6 +34,7 @@ def main() -> int:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--max-len", type=int, default=96)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = (get_reduced(args.arch) if args.reduced else get_config(args.arch)).with_(
         dtype="float32" if args.reduced else "bfloat16"

@@ -3,10 +3,11 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \\
         --reduced --steps 50 --batch 8 --seq 128 --ckpt /tmp/ckpt
 
-On this CPU container use --reduced (the smoke config of the same family);
-on a real pod omit it and pass --mesh-from-env. Steps run as registered FaaS
-functions on a local endpoint (routing + warming + retry + telemetry), the
-checkpointer bounds restart loss, and the data pipeline prefetches.
+--reduced trains the smoke config of the same family (what a CPU run can
+hold); without it the full published config trains on the default device.
+Steps run as registered FaaS functions on a local endpoint (routing +
+warming + retry + telemetry), the checkpointer bounds restart loss, and the
+data pipeline prefetches.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import json
 
 from repro.configs import ARCH_IDS, get_config, get_reduced
 from repro.core import FunctionService
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import Model
 from repro.training.optimizer import OptimizerConfig
 from repro.training.train_loop import TrainConfig, Trainer
@@ -34,6 +36,7 @@ def main() -> int:
     ap.add_argument("--no-faas", action="store_true", help="run steps inline")
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     model = Model(cfg)

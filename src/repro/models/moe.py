@@ -141,21 +141,6 @@ def _moe_ffn_shard_map(x: jnp.ndarray, p, cfg: ModelConfig):
     global scatter/gather formulations (see EXPERIMENTS.md)."""
     from jax.sharding import PartitionSpec as P
 
-    try:  # jax>=0.6 moved shard_map to the top level
-        from jax import shard_map as _shard_map
-        shard_map = _shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
-    import inspect
-
-    # the replication-check kwarg was renamed check_rep -> check_vma
-    check_kw = (
-        "check_vma"
-        if "check_vma" in inspect.signature(shard_map).parameters
-        else "check_rep"
-    )
-
     ctx = partition.current()
     mesh = ctx.mesh
     m = cfg.moe
@@ -175,7 +160,7 @@ def _moe_ffn_shard_map(x: jnp.ndarray, p, cfg: ModelConfig):
             aux = jax.lax.pmean(aux, batch_axes)
         return y.reshape(xb.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -184,7 +169,7 @@ def _moe_ffn_shard_map(x: jnp.ndarray, p, cfg: ModelConfig):
             P("model"), P("model"), P("model"),          # experts over model
         ),
         out_specs=(P(batch_axes if batch_axes else None), P()),
-        **{check_kw: False},
+        check_vma=False,
     )(x, p["router"], p["wi"], p["wg"], p["wo"])
     return y, aux
 

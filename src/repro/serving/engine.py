@@ -59,7 +59,7 @@ class ServeEngine:
 
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
-        self._insert = jax.jit(kv_cache.insert_sequence, static_argnums=(2,))
+        self._insert = jax.jit(kv_cache.insert_sequence)
 
         cache, _ = model.init_cache(max_batch, max_len)
         self.cache = cache

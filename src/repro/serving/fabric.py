@@ -202,7 +202,7 @@ class ModelHost:
 
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
-        self._insert = jax.jit(kv_cache.insert_sequence, static_argnums=(2,))
+        self._insert = jax.jit(kv_cache.insert_sequence)
 
         self._lock = threading.Lock()
         self.sessions: Dict[str, _SessionState] = {}
@@ -217,6 +217,14 @@ class ModelHost:
                 target_fn=lambda: len(self.sessions),
             )
         else:
+            # each session decodes in a private batch-1 cache of max_len
+            # positions; the prompt-length cache prefill returns goes into
+            # a fresh one so decode writes land inside the cache
+            self._to_decode_cache = jax.jit(
+                lambda c: kv_cache.insert_sequence(
+                    model.init_cache(1, max_len)[0], c, 0
+                )
+            )
             self.coalescer = None
 
     # -- metrics helpers ---------------------------------------------------
@@ -253,6 +261,8 @@ class ModelHost:
             )
         logits, seq_cache = self._prefill(self.params, batch)
         first = int(jnp.argmax(logits[0]))
+        if not self.batching:
+            seq_cache = self._to_decode_cache(seq_cache)
         with self._lock:
             if self.batching:
                 self.cache = self._insert(self.cache, seq_cache, slot)

@@ -39,23 +39,22 @@ def cache_bytes(cfg: ModelConfig, batch: int, seq_len: int) -> int:
     return cfg.n_layers * per_layer + attn
 
 
-def insert_sequence(batched_cache: Any, seq_cache: Any, slot: int, batch_axis: int = 1) -> Any:
+def insert_sequence(batched_cache: Any, seq_cache: Any, slot, batch_axis: int = 1) -> Any:
     """Place a single-sequence cache (batch dim 1) into slot `slot` of a
     batched cache. Caches are stacked over layers on axis 0, so the batch
-    axis is 1 by convention."""
+    axis is 1 by convention. `slot` may be traced: one compiled program
+    serves every slot."""
 
     def put(dst, src):
-        idx = [slice(None)] * dst.ndim
-        idx[batch_axis] = slice(slot, slot + 1)
-        # pad/trim src seq dims up to dst
+        # pad src seq dims up to dst
         pads = []
         for d in range(src.ndim):
             if d == batch_axis or src.shape[d] == dst.shape[d]:
                 pads.append((0, 0))
             else:
                 pads.append((0, dst.shape[d] - src.shape[d]))
-        src = jnp.pad(src, pads)
-        return dst.at[tuple(idx)].set(src.astype(dst.dtype))
+        src = jnp.pad(src, pads).astype(dst.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(dst, src, slot, axis=batch_axis)
 
     return jax.tree.map(put, batched_cache, seq_cache)
 

@@ -242,10 +242,39 @@ def test_heartbeat_dead_detection():
     for _ in range(3):
         mon.beat("b")
         time.sleep(0.01)
-    dead = mon.dead()
+    assert mon.dead() == []  # the first pass has nothing to judge against
+    mon.beat("b")
+    dead = mon.dead()  # "a" was past the limit at that pass, silent since
     assert "a" in dead and "b" not in dead
     mon.suspend("a")
     assert "a" not in mon.dead()  # suspended are not re-reported
+    assert mon.revived() == []
+    mon.beat("a")  # the death was a false positive: "a" beats again
+    assert mon.revived() == ["a"]
+    mon.resume("a")
+    assert mon.revived() == [] and "a" not in mon.dead()
+
+
+def test_heartbeat_stall_of_the_process_is_not_a_death():
+    """A stall of the whole process stops beats and watchdog passes alike.
+    When the pass runs first after it, it finds the beat past the death
+    limit (0.5 s), but the previous pass did not; the beat that follows is
+    newer than that pass, so no death, however long or frequent the stalls.
+    An executor that stops beating is dead at the first pass after one that
+    found it past the limit."""
+    mon = HeartbeatMonitor(interval_s=0.25, threshold=2.0)
+    mon.register("a", now=0.0)
+    mon.beat("a", now=0.2)
+    assert mon.dead(now=0.26) == []
+    assert mon.dead(now=0.75) == []  # stall 0.27-0.75: the beat is 0.55 s old
+    mon.beat("a", now=0.751)
+    for t in (1.0, 1.6, 2.2, 2.8):  # stalls of 0.6 s, one beat between each
+        assert mon.dead(now=t) == []
+        mon.beat("a", now=t + 0.001)
+    assert mon.dead(now=3.05) == []  # the beat at 2.801 is newer than 2.8
+    assert mon.dead(now=3.3) == []  # 0.499 s old: inside the limit
+    assert mon.dead(now=3.55) == []  # past the limit, but not at 3.3
+    assert mon.dead(now=3.8) == ["a"]  # past it at 3.55 too, silent since
 
 
 def test_latency_tracker_p95():

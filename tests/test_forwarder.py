@@ -207,18 +207,142 @@ def test_false_positive_death_resurrects_on_fresh_heartbeat():
     ep = svc.make_endpoint("fp", n_executors=1, workers_per_executor=1)
     fid = svc.register_function(_sleepy)
     svc.run(fid, {"i": 0, "t": 0.0}).result(10)
-    svc.forwarder.liveness_threshold_s = 1e-9  # every endpoint looks dead
+    # running, but every heartbeat looks stale
+    ep.is_alive = lambda max_heartbeat_age_s=None: max_heartbeat_age_s is None
     deadline = time.monotonic() + 2
     while not svc.forwarder.stats()["endpoints"][ep.endpoint_id]["dead"]:
         assert time.monotonic() < deadline, "watchdog never marked endpoint dead"
         time.sleep(0.01)
-    svc.forwarder.liveness_threshold_s = 2.0  # heartbeat is fresh again
+    del ep.is_alive  # heartbeat is fresh again
     deadline = time.monotonic() + 2
     while svc.forwarder.stats()["endpoints"][ep.endpoint_id]["dead"]:
         assert time.monotonic() < deadline, "endpoint was never resurrected"
         time.sleep(0.01)
     out = svc.run(fid, {"i": 7, "t": 0.0}, sync=True, timeout=10)
     assert out["i"] == 7
+    svc.shutdown()
+
+
+class StallingEndpoint(FakeEndpoint):
+    """Running, with its last heartbeat at `beat_at` (monotonic clock)."""
+
+    def __init__(self, eid):
+        super().__init__(eid)
+        self.beat_at = time.monotonic()
+
+    def is_alive(self, max_heartbeat_age_s=None):
+        if max_heartbeat_age_s is None:
+            return self._alive
+        return self._alive and time.monotonic() - self.beat_at <= max_heartbeat_age_s
+
+
+def test_one_stale_pass_keeps_endpoint_and_its_sessions(fwd_factory):
+    """The first watchdog pass after a stall of the whole process can find
+    any heartbeat past the threshold. Calling that a death would evict the
+    endpoint's sessions (their next step re-prefills elsewhere) and fail its
+    tasks over, so an endpoint is dead only when the previous pass had
+    already found it past the threshold and it has not beaten since."""
+    a, b = StallingEndpoint("a"), StallingEndpoint("b")
+    fwd = fwd_factory("least_outstanding", [a, b], watchdog_interval_s=3600,
+                      liveness_threshold_s=0.2)
+    fwd.sessions.bind("s", "a")
+    assert fwd.check_endpoints() == []
+    time.sleep(0.3)  # a stall: no beat, no pass
+    assert fwd.check_endpoints() == []  # fine at the previous pass
+    assert fwd.sessions.lookup("s") == "a"
+    a.beat_at = b.beat_at = time.monotonic()  # both beat after it
+    time.sleep(0.3)  # another stall
+    assert fwd.check_endpoints() == []  # beat since the previous pass
+    time.sleep(0.05)
+    b.beat_at = time.monotonic()
+    assert fwd.check_endpoints() == ["a"]  # past it then, silent since
+    assert fwd.sessions.lookup("s") is None
+    assert fwd.metrics.snapshot()["counters"]["forwarder.session_evictions"] == 1
+    b._alive = False  # a stopped endpoint is dead at the next pass
+    assert fwd.check_endpoints() == ["b"]
+
+
+def _hold_gil(doc):
+    """Hold the GIL `rounds` times for at least `s` seconds each in C (a sum
+    over a range never yields), as an XLA compile or executable load can:
+    every other thread stalls. The gaps between holds vary from 0.02 to
+    0.2 s, so stalls start at every phase of the heartbeat and watchdog
+    cycles. Returns the shortest hold."""
+    n, held = 10**6, []
+    while len(held) < doc["rounds"]:
+        t0 = time.monotonic()
+        sum(range(n))
+        dt = time.monotonic() - t0
+        if dt >= doc["s"]:
+            held.append(dt)
+            time.sleep(0.02 + 0.03 * (len(held) % 7))
+        else:  # too short: grow toward the target and hold again
+            n = int(n * 1.2 * doc["s"] / max(dt, 1e-6))
+    return min(held)
+
+
+def test_gil_stall_keeps_a_session_on_its_endpoint():
+    """On a two-endpoint fabric, a session's task holds the GIL past the
+    Forwarder's liveness threshold, several times: its endpoint is not
+    declared dead, so its session is not evicted and its task does not fail
+    over to the other endpoint."""
+    svc = FunctionService()
+    svc.forwarder.liveness_threshold_s = 0.6
+    for i in range(2):
+        svc.make_endpoint(f"gil{i}", n_executors=1, workers_per_executor=1)
+    fid = svc.register_function(_hold_gil)
+    assert svc.run(fid, {"s": 0.8, "rounds": 6}, session_id="s").result(60) >= 0.8
+    counters = svc.metrics.snapshot()["counters"]
+    assert counters.get("forwarder.session_evictions", 0) == 0
+    assert counters.get("forwarder.failovers", 0) == 0
+    assert counters.get("endpoint.executors_lost", 0) == 0
+    svc.shutdown()
+
+
+@pytest.mark.parametrize(
+    "hold_s,rounds", [(0.3, 12), (0.4, 12), (0.8, 4), (2.5, 1)]
+)
+def test_gil_stall_is_not_a_death(hold_s, rounds):
+    """Tasks that hold the GIL for one to two heartbeat intervals (0.3 and
+    0.4 s: the executor's beat can then be past the 0.5 s death limit when
+    the stall ends), past the death limit, or past the endpoint liveness
+    threshold (2 s) stall the watchdogs too: nothing is declared dead, and
+    the endpoint's one executor runs the next task. (Whether a stalled
+    watchdog or the executor's heartbeat runs first after a stall is a race;
+    several stalls make losing it likely.)"""
+    svc = FunctionService()
+    svc.make_endpoint("gil", n_executors=1, workers_per_executor=1)
+    fid = svc.register_function(_hold_gil)
+    held = svc.run(fid, {"s": hold_s, "rounds": rounds}).result(30)
+    assert held >= hold_s
+    assert svc.run(fid, {"s": 0.0, "rounds": 1}).result(10) < 0.5
+    counters = svc.metrics.snapshot()["counters"]
+    assert counters.get("endpoint.executors_lost", 0) == 0
+    assert counters.get("forwarder.failovers", 0) == 0
+    svc.shutdown()
+
+
+def test_falsely_dead_executor_is_taken_back_when_it_beats_again():
+    """An executor whose beats stop for longer than the death limit is
+    declared dead; when it beats again the endpoint takes it back, so a
+    non-elastic endpoint with one executor runs its next tasks instead of
+    queueing them forever."""
+    svc = FunctionService()
+    ep = svc.make_endpoint("fp", n_executors=1, workers_per_executor=1)
+    fid = svc.register_function(_sleepy)
+    assert svc.run(fid, {"i": 0, "t": 0.0}).result(10) == {"i": 0}
+    real_beat = ep.monitor.beat
+    ep.monitor.beat = lambda executor_id, now=None: None  # beats held off
+    counters = svc.metrics.snapshot
+    deadline = time.monotonic() + 5
+    while counters()["counters"].get("endpoint.executors_lost", 0) < 1:
+        assert time.monotonic() < deadline, "watchdog never declared a death"
+        time.sleep(0.02)
+    assert ep.executors == {}  # no executor left to run the queue
+    ep.monitor.beat = real_beat  # the executor beats again
+    assert svc.run(fid, {"i": 1, "t": 0.0}).result(10) == {"i": 1}
+    assert counters()["counters"].get("endpoint.executors_readmitted", 0) == 1
+    assert ep.provider.status()["blocks"] == 1
     svc.shutdown()
 
 

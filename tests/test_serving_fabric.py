@@ -185,6 +185,24 @@ def test_unbatched_host_matches_reference(small_model):
     assert toks == _greedy_reference(model, params, prompt, 4)
 
 
+@pytest.mark.parametrize("arch", ["mamba2-2.7b", "zamba2-2.7b"])
+def test_recurrent_family_host_matches_reference(arch):
+    """ssm and hybrid families always serve unbatched; hybrid's shared
+    attention KV must decode inside its max_len cache like dense KV does."""
+    model = Model(get_reduced(arch).with_(dtype="float32"))
+    params = model.init(jax.random.PRNGKey(0))
+    host = ModelHost(model, params, max_len=48, max_sessions=2)
+    assert not host.batching
+    prompt = np.random.default_rng(4).integers(0, model.cfg.vocab, 6)
+    toks = [host.prefill("s1", prompt)]
+    history = list(prompt) + toks
+    for _ in range(3):
+        nxt, _ = host.decode("s1", history)
+        toks.append(nxt)
+        history.append(nxt)
+    assert toks == _greedy_reference(model, params, prompt, 4)
+
+
 # ------------------------------------------------------------- admission
 def test_cache_bytes_admission_control(small_model):
     from repro.serving.kv_cache import cache_bytes

@@ -92,6 +92,24 @@ def test_jax_jit_function_warm_faster_than_cold(service):
     assert warm < cold, (warm, cold)
 
 
+def test_jax_jit_refused_compile_fails_task_without_warm_entry(service):
+    """A compile the backend refuses fails the task; it neither counts as a
+    cold start nor records a compile time for a program that never built."""
+    import jax.numpy as jnp
+
+    def mismatched(doc):
+        return {"z": jnp.dot(doc["a"], doc["b"])}
+
+    fid = service.register_function(mismatched, name="mismatched", jax_jit=True)
+    fut = service.run(fid, {"a": np.ones((3, 4), np.float32),
+                            "b": np.ones((5, 6), np.float32)}, max_retries=0)
+    with pytest.raises(TypeError):
+        fut.result(60)
+    snap = service.metrics.snapshot()
+    assert snap["counters"].get("warming.cold_starts", 0) == 0
+    assert snap["histograms"].get("warming.compile_time_s", {}).get("count", 0) == 0
+
+
 def test_auth_scopes_enforced():
     authority = TokenAuthority()
     svc = FunctionService(authority=authority)
