@@ -318,47 +318,50 @@ class Endpoint:
 
     def _handle_result(self, res: TaskResult, fut: Optional[TaskFuture] = None) -> None:
         env = res.envelope
-        if fut is None:
-            with self._flock:
-                fut = self.futures.get(env.task_id)
-        if fut is None:
-            return
-        if res.error is not None:
-            if env.retries < env.max_retries:
-                self.requeued += 1
-                self.metrics.counter("endpoint.tasks_requeued").inc()
-                retry = env.clone_for_retry()
+        # covers set_result and the done-callbacks it runs (forwarder
+        # bookkeeping, the service's completion hook, client callbacks)
+        with self.metrics.task(env.task_id), self.metrics.span("endpoint.result"):
+            if fut is None:
                 with self._flock:
-                    self.futures[retry.task_id] = fut
-                with self._qlock:
-                    self._queue.appendleft(retry)
-            else:
-                self._speculated.discard(env.speculative_of or env.task_id)
-                if not fut.set_exception(res.exception or RuntimeError(res.error)):
-                    # the future already resolved (speculative copy, replayed
-                    # frame, cancelled client): exactly-once held, count it
-                    self.metrics.counter("journal.duplicate_results").inc()
-            return
-        # prune straggler bookkeeping once either copy delivers (the set
-        # otherwise grows without bound under long-running speculation)
-        self._speculated.discard(env.speculative_of or env.task_id)
-        won = fut.set_result(res.value)
-        if not won:
-            # a second completion for an already-resolved future (speculation
-            # loser, duplicated/replayed ResultBatch delivery): dedupe to
-            # exactly-once resolution and count the duplicate
-            self.metrics.counter("journal.duplicate_results").inc()
-        if won:
-            self.completed += 1
-            self.metrics.counter("endpoint.tasks_completed").inc()
-            ts = env.timestamps
-            if ts.exec_end and ts.endpoint_in:
-                self.tracker.record(ts.exec_end - ts.endpoint_in)
-            if self.result_hook is not None:
-                try:
-                    self.result_hook(env, res)
-                except Exception:
-                    pass
+                    fut = self.futures.get(env.task_id)
+            if fut is None:
+                return
+            if res.error is not None:
+                if env.retries < env.max_retries:
+                    self.requeued += 1
+                    self.metrics.counter("endpoint.tasks_requeued").inc()
+                    retry = env.clone_for_retry()
+                    with self._flock:
+                        self.futures[retry.task_id] = fut
+                    with self._qlock:
+                        self._queue.appendleft(retry)
+                else:
+                    self._speculated.discard(env.speculative_of or env.task_id)
+                    if not fut.set_exception(res.exception or RuntimeError(res.error)):
+                        # the future already resolved (speculative copy, replayed
+                        # frame, cancelled client): exactly-once held, count it
+                        self.metrics.counter("journal.duplicate_results").inc()
+                return
+            # prune straggler bookkeeping once either copy delivers (the set
+            # otherwise grows without bound under long-running speculation)
+            self._speculated.discard(env.speculative_of or env.task_id)
+            won = fut.set_result(res.value)
+            if not won:
+                # a second completion for an already-resolved future (speculation
+                # loser, duplicated/replayed ResultBatch delivery): dedupe to
+                # exactly-once resolution and count the duplicate
+                self.metrics.counter("journal.duplicate_results").inc()
+            if won:
+                self.completed += 1
+                self.metrics.counter("endpoint.tasks_completed").inc()
+                ts = env.timestamps
+                if ts.exec_end and ts.endpoint_in:
+                    self.tracker.record(ts.exec_end - ts.endpoint_in)
+                if self.result_hook is not None:
+                    try:
+                        self.result_hook(env, res)
+                    except Exception:
+                        pass
 
     def _dispatch(self) -> None:
         """Capacity-pulled batch dispatch (paper §5.3/§5.5): each round picks
@@ -405,47 +408,48 @@ class Endpoint:
             dispatch_latency = self.metrics.histogram("endpoint.dispatch_latency_s")
             ready: List[TaskEnvelope] = []
             for env in chunk:
-                # queue-time memoization: a result computed while this task
-                # waited serves it without dispatch (paper Table 3)
-                if env.memoize and self.memo_probe is not None:
-                    hit, value = self.memo_probe(env)
-                    if hit:
-                        with self._flock:
-                            fut = self.futures.get(env.task_id)
-                        if fut is not None and fut.set_result(value, TaskState.MEMOIZED):
-                            self.completed += 1
-                        continue
-                # data fabric: pull every blob the payload references into
-                # the site-local cache (one store read per NEW key — raw
-                # bytes only, nothing is unpacked or repacked on this serial
-                # loop). Workers then materialize values in parallel from
-                # the warmed cache via the env.data_cache handle.
-                if env.data_refs and isinstance(env.payload, (bytes, bytearray)):
-                    try:
-                        payload = serializer.unpackb(env.payload)
-                        prefetch_refs(
-                            scan_refs(payload), self.data_cache,
-                            metrics=self.metrics,
-                        )
-                        env.payload = payload
-                        env.data_cache = self.data_cache
-                        env.data_decoded = self.data_decoded
-                    except Exception as exc:
-                        with self._flock:
-                            fut = self.futures.get(env.task_id)
-                        if fut is not None:
-                            fut.set_exception(
-                                KeyError(
-                                    f"task {env.task_id}: payload data "
-                                    f"unresolvable at {self.name!r}: {exc}"
-                                )
+                with self.metrics.task(env.task_id), self.metrics.span("endpoint.dispatch"):
+                    # queue-time memoization: a result computed while this task
+                    # waited serves it without dispatch (paper Table 3)
+                    if env.memoize and self.memo_probe is not None:
+                        hit, value = self.memo_probe(env)
+                        if hit:
+                            with self._flock:
+                                fut = self.futures.get(env.task_id)
+                            if fut is not None and fut.set_result(value, TaskState.MEMOIZED):
+                                self.completed += 1
+                            continue
+                    # data fabric: pull every blob the payload references into
+                    # the site-local cache (one store read per NEW key — raw
+                    # bytes only, nothing is unpacked or repacked on this serial
+                    # loop). Workers then materialize values in parallel from
+                    # the warmed cache via the env.data_cache handle.
+                    if env.data_refs and isinstance(env.payload, (bytes, bytearray)):
+                        try:
+                            payload = serializer.unpackb(env.payload)
+                            prefetch_refs(
+                                scan_refs(payload), self.data_cache,
+                                metrics=self.metrics,
                             )
-                        continue
-                env.timestamps.dispatched = now
-                if env.timestamps.endpoint_in:
-                    dispatch_latency.observe(now - env.timestamps.endpoint_in)
-                env.site = self.site  # where this attempt runs (site-aware fns)
-                ready.append(env)
+                            env.payload = payload
+                            env.data_cache = self.data_cache
+                            env.data_decoded = self.data_decoded
+                        except Exception as exc:
+                            with self._flock:
+                                fut = self.futures.get(env.task_id)
+                            if fut is not None:
+                                fut.set_exception(
+                                    KeyError(
+                                        f"task {env.task_id}: payload data "
+                                        f"unresolvable at {self.name!r}: {exc}"
+                                    )
+                                )
+                            continue
+                    env.timestamps.dispatched = now
+                    if env.timestamps.endpoint_in:
+                        dispatch_latency.observe(now - env.timestamps.endpoint_in)
+                    env.site = self.site  # where this attempt runs (site-aware fns)
+                    ready.append(env)
             if not ready:
                 continue
             with self._flock:

@@ -234,17 +234,18 @@ class Executor:
                     results.append(self._outbox.get_nowait())
                 except queue.Empty:
                     break
-            with self._lock:
+            with self.metrics.span("executor.result"):
+                with self._lock:
+                    for r in results:
+                        self.in_flight.pop(r.envelope.task_id, None)
+                    self.completed += len(results)
+                self.metrics.counter("executor.tasks_executed").inc(len(results))
+                service_time = self.metrics.histogram("executor.service_time_s")
                 for r in results:
-                    self.in_flight.pop(r.envelope.task_id, None)
-                self.completed += len(results)
-            self.metrics.counter("executor.tasks_executed").inc(len(results))
-            service_time = self.metrics.histogram("executor.service_time_s")
-            for r in results:
-                ts = r.envelope.timestamps
-                if ts.exec_end and ts.exec_start:
-                    service_time.observe(ts.exec_end - ts.exec_start)
-            self.result_queue.put(ResultBatch(results=results))
+                    ts = r.envelope.timestamps
+                    if ts.exec_end and ts.exec_start:
+                        service_time.observe(ts.exec_end - ts.exec_start)
+                self.result_queue.put(ResultBatch(results=results))
 
     def _beat_loop(self) -> None:
         while self._alive:

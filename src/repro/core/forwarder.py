@@ -647,7 +647,8 @@ class Forwarder:
         routed_pairs: List[_Pair] = []
         rejected: List[Tuple[TaskFuture, CapabilityError]] = []
         deliveries: Dict[str, Tuple[EndpointRecord, List[_Pair]]] = {}
-        with self._lock:
+        with self.metrics.span("forwarder.route"), \
+                self.metrics.locked(self._lock, "forwarder.lock_wait"):
             pinned: Optional[EndpointRecord] = None
             pinned_caps: Optional[frozenset] = None
             if endpoint_id is not None:
@@ -749,18 +750,19 @@ class Forwarder:
         surface, e.g. test fakes)."""
         submit_batch = getattr(endpoint, "submit_batch", None)
         for frame in iter_frames(pairs, self.max_batch):
-            with self._lock:
-                self.batches_delivered += 1
-                self.tasks_delivered += len(frame)
-            self.metrics.counter("forwarder.batches_delivered").inc()
-            self.metrics.histogram(
-                "forwarder.batch_size", buckets=SIZE_BUCKETS
-            ).observe(len(frame))
-            if submit_batch is not None:
-                submit_batch(frame)
-            else:
-                for env, future in frame.pairs():
-                    endpoint.submit(env, future)
+            with self.metrics.span("forwarder.route"):
+                with self.metrics.locked(self._lock, "forwarder.lock_wait"):
+                    self.batches_delivered += 1
+                    self.tasks_delivered += len(frame)
+                self.metrics.counter("forwarder.batches_delivered").inc()
+                self.metrics.histogram(
+                    "forwarder.batch_size", buckets=SIZE_BUCKETS
+                ).observe(len(frame))
+                if submit_batch is not None:
+                    submit_batch(frame)
+                else:
+                    for env, future in frame.pairs():
+                        endpoint.submit(env, future)
 
     # -- submit-queue pump ----------------------------------------------------
     def _pump_loop(self) -> None:

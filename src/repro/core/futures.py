@@ -55,13 +55,23 @@ class Timestamps:
         t_w: service routing (accept -> endpoint queue)
         t_m: endpoint/manager latency (queue + dispatch + worker pickup)
         t_e: function execution time
+
+        and t_m's two parts plus the return path:
+
+        t_q: endpoint queue (endpoint queue -> handed to an executor)
+        t_p: worker pickup (handed to an executor -> worker began)
+        t_r: result return (worker finished -> future completed)
         """
         t_e = max(0.0, self.exec_end - self.exec_start)
         t_m = max(0.0, self.exec_start - self.endpoint_in)
+        t_q = min(t_m, max(0.0, self.dispatched - self.endpoint_in))
+        t_p = t_m - t_q
         t_w = max(0.0, self.endpoint_in - self.service_in)
+        t_r = max(0.0, self.result_ready - self.exec_end)
         total = max(0.0, self.result_ready - self.client_submit)
         t_c = max(0.0, total - t_w - t_m - t_e)
-        return {"t_c": t_c, "t_w": t_w, "t_m": t_m, "t_e": t_e, "total": total}
+        return {"t_c": t_c, "t_w": t_w, "t_m": t_m, "t_e": t_e, "total": total,
+                "t_q": t_q, "t_p": t_p, "t_r": t_r}
 
 
 @dataclass

@@ -25,11 +25,22 @@ Instruments are cheap: recording is a lock-free attribute bump guarded by a
 per-instrument lock only where read-modify-write requires it; registry lookup
 is a dict get. The hot path (one histogram observation per task) costs well
 under a microsecond.
+
+The registry also records **spans**: named intervals of host work, each
+stamped with ``time.monotonic_ns()`` and tagged with the task the thread is
+working for and the enclosing span. They are off by default, where
+``span()``, ``task()`` and ``locked()`` hand back one shared no-op object
+(or the lock itself) and touch nothing; ``enable_spans(capacity)`` turns
+them on into a bounded ring that ``take_spans()`` empties. On the monotonic
+clock they line up with a profiler trace that carries one annotation taken
+at a known ``time.monotonic_ns()``.
 """
 from __future__ import annotations
 
+import collections
 import threading
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+import time
+from typing import Dict, Iterable, List, NamedTuple, Optional, Sequence, Tuple
 
 # Default buckets for latency-flavoured histograms (seconds): 1ms → 60s,
 # roughly geometric, matching the dynamic range of Fig. 4/5.
@@ -184,6 +195,116 @@ class Histogram:
         return d
 
 
+class Span(NamedTuple):
+    """One recorded interval of host work (``time.monotonic_ns()`` stamps)."""
+
+    name: str
+    task: Optional[str]        # task the thread was working for, if any
+    parent: Optional[str]      # name of the enclosing open span, if any
+    thread: int                # threading.get_ident() of the recording thread
+    start_ns: int
+    end_ns: int
+
+
+class _Context(threading.local):
+    """Per-thread context shared by every registry: the task this thread
+    works for, the registry its spans go to, and the names of its open spans.
+    The class defaults keep a read of an unset field off the exception path."""
+
+    task: Optional[str] = None
+    registry: Optional["MetricsRegistry"] = None
+    stack: Optional[List[str]] = None
+
+
+_context = _Context()
+
+
+class _NoSpan:
+    """What span(), task() and current_span() hand out while spans are off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NO_SPAN = _NoSpan()
+
+
+class _Span:
+    __slots__ = ("_registry", "_name", "_parent", "_start")
+
+    def __init__(self, registry: "MetricsRegistry", name: str):
+        self._registry = registry
+        self._name = name
+
+    def __enter__(self):
+        stack = _context.stack
+        if stack is None:
+            stack = _context.stack = []
+        self._parent = stack[-1] if stack else None
+        stack.append(self._name)
+        self._start = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        end = time.monotonic_ns()
+        ctx = _context
+        ctx.stack.pop()
+        # a plain tuple here; take_spans() makes it a Span
+        self._registry._record((self._name, ctx.task, self._parent,
+                                threading.get_ident(), self._start, end))
+        return False
+
+
+class _TaskScope:
+    """Tags the spans this thread records with `task` until exit."""
+
+    __slots__ = ("_registry", "_task", "_outer")
+
+    def __init__(self, registry: "MetricsRegistry", task: Optional[str]):
+        self._registry = registry
+        self._task = task
+
+    def __enter__(self):
+        self._outer = (_context.task, _context.registry)
+        _context.task, _context.registry = self._task, self._registry
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        _context.task, _context.registry = self._outer
+        return False
+
+
+class _TimedAcquire:
+    """A lock whose acquisition is recorded as a span of its own."""
+
+    __slots__ = ("_registry", "_lock", "_name")
+
+    def __init__(self, registry: "MetricsRegistry", lock, name: str):
+        self._registry, self._lock, self._name = registry, lock, name
+
+    def __enter__(self):
+        with _Span(self._registry, self._name):
+            self._lock.acquire()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self._lock.release()
+        return False
+
+
+def current_span(name: str):
+    """A span in the registry of the task this thread runs (set by
+    :meth:`MetricsRegistry.task`), for code that holds no registry of its
+    own; NO_SPAN outside a task or while spans are off."""
+    registry = _context.registry
+    return NO_SPAN if registry is None else registry.span(name)
+
+
 class MetricsRegistry:
     """Get-or-create instrument registry with snapshot/export.
 
@@ -199,6 +320,45 @@ class MetricsRegistry:
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
+        self._spans: Optional[collections.deque] = None   # None: spans off
+        self._spans_lock = threading.Lock()
+
+    # -- spans -------------------------------------------------------------
+    def enable_spans(self, capacity: int = 1 << 16) -> None:
+        """Record spans from now on, keeping the newest `capacity`; older
+        ones are dropped and counted in ``telemetry.spans_dropped``."""
+        with self._spans_lock:
+            self._spans = collections.deque(maxlen=int(capacity))
+
+    def take_spans(self) -> List[Span]:
+        """The spans recorded since the last call, oldest first."""
+        with self._spans_lock:
+            if self._spans is None:
+                return []
+            out = list(self._spans)
+            self._spans.clear()
+        return [Span._make(s) for s in out]
+
+    def span(self, name: str):
+        """Context manager recording one span named `name` on this thread."""
+        return NO_SPAN if self._spans is None else _Span(self, name)
+
+    def task(self, task_id: Optional[str]):
+        """Context manager tagging this thread's spans with `task_id`."""
+        return NO_SPAN if self._spans is None else _TaskScope(self, task_id)
+
+    def locked(self, lock, name: str):
+        """`lock` as a context manager whose wait is recorded as span `name`."""
+        return lock if self._spans is None else _TimedAcquire(self, lock, name)
+
+    def _record(self, span: tuple) -> None:
+        with self._spans_lock:
+            ring = self._spans
+            if ring is None:
+                return
+            if len(ring) == ring.maxlen:
+                self.counter("telemetry.spans_dropped").inc()
+            ring.append(span)
 
     # -- instrument access -------------------------------------------------
     def counter(self, name: str, labels: Optional[Dict[str, str]] = None) -> Counter:

@@ -243,6 +243,18 @@ class FunctionService:
         results substituted in place — an upstream failure fails the dependent
         task without it ever reaching an endpoint.
         """
+        # the task ids come first so a lone task's submit span carries its id
+        task_ids = [new_task_id() for _ in invocations]
+        with self.metrics.task(task_ids[0] if len(task_ids) == 1 else None), \
+                self.metrics.span("service.submit"):
+            return self._submit_batch(invocations, task_ids, token)
+
+    def _submit_batch(
+        self,
+        invocations: Sequence[Invocation],
+        task_ids: List[str],
+        token: Optional[Token],
+    ) -> List[TaskFuture]:
         t_submit = time.monotonic()
         identity = self._identity(token, auth_mod.SCOPE_INVOKE)
         fns = {}
@@ -257,11 +269,11 @@ class FunctionService:
 
         futures: List[TaskFuture] = []
         groups: Dict[Optional[str], List[Tuple[TaskEnvelope, TaskFuture]]] = {}
-        for inv in invocations:
+        for inv, task_id in zip(invocations, task_ids):
             rf = fns[inv.function_id]
             wire = rf.metadata.get("pass_through", False)
             memoizable = inv.memoize and rf.deterministic and not wire
-            future = TaskFuture(new_task_id())
+            future = TaskFuture(task_id)
             future.timestamps.client_submit = t_submit
             future.timestamps.service_in = t_service_in
             future.add_done_callback(self._observe_completion)
@@ -672,9 +684,12 @@ class FunctionService:
     def fetch(self, value: Any, timeout: Optional[float] = None) -> Any:
         """Materialize any DataRef leaves in `value` (a result, a payload, or
         a TaskFuture whose result may carry spilled leaves)."""
+        task_id = None
         if isinstance(value, TaskFuture):
+            task_id = value.task_id
             value = value.result(timeout)
-        return resolve_payload(value, metrics=self.metrics)
+        with self.metrics.task(task_id), self.metrics.span("service.fetch"):
+            return resolve_payload(value, metrics=self.metrics)
 
     # -- hooks -----------------------------------------------------------------
     def _observe_completion(self, future: TaskFuture) -> None:

@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import TYPE_CHECKING, Any, Callable, Optional
 
 from . import serializer
+from .metrics import current_span
 
 if TYPE_CHECKING:  # imported lazily to avoid a registry<->containers cycle
     from .registry import RegisteredFunction
@@ -130,10 +131,14 @@ class _JaxExecutable:
             self._jitted.lower(sample_payload).compile()
 
     def __call__(self, payload: Any) -> Any:
-        out = self._jitted(payload)
         import jax
 
-        return jax.block_until_ready(out)
+        # the call returns once its inputs are on the device and the program
+        # is enqueued; the wait after it is the device's
+        with current_span("worker.dispatch"):
+            out = self._jitted(payload)
+        with current_span("worker.block"):
+            return jax.block_until_ready(out)
 
 
 def build_executable(rf: "RegisteredFunction", sample_payload: Any = None) -> Callable:
@@ -199,7 +204,8 @@ class Worker(threading.Thread):
                 return  # vanish without reporting — watchdog must recover
             self.busy = True
             try:
-                result = self._execute(item)
+                with self.warm_pool.metrics.task(item.task_id):
+                    result = self._execute(item)
             finally:
                 self.busy = False
             if self._drop_inflight.is_set():
@@ -209,9 +215,11 @@ class Worker(threading.Thread):
 
     def _execute(self, env) -> TaskResult:
         env.timestamps.exec_start = time.monotonic()
+        metrics = self.warm_pool.metrics
         try:
             rf = self.registry.get(env.function_id)
-            payload = serializer.unpackb(env.payload) if isinstance(env.payload, bytes) else env.payload
+            with metrics.span("worker.unpack"):
+                payload = serializer.unpackb(env.payload) if isinstance(env.payload, bytes) else env.payload
             if getattr(env, "data_refs", ()):
                 # materialize DataRef leaves in parallel across workers; the
                 # dispatching endpoint warmed its locality cache and attached
@@ -220,34 +228,38 @@ class Worker(threading.Thread):
                 # payloads) resolves straight from the refs' store locations.
                 from .datastore import resolve_payload
 
-                payload = resolve_payload(
-                    payload,
-                    cache=getattr(env, "data_cache", None),
-                    decoded=getattr(env, "data_decoded", None),
-                )
+                with metrics.span("data.resolve"):
+                    payload = resolve_payload(
+                        payload,
+                        cache=getattr(env, "data_cache", None),
+                        decoded=getattr(env, "data_decoded", None),
+                    )
             key = (env.function_id, env.container)
             executable, cold, dt = self.warm_pool.get_or_compile(
                 key, lambda: build_executable(rf, payload)
             )
-            if rf.metadata.get("site_aware", False):
-                # endpoint-scoped functions see where they run: the serving
-                # tier resolves its per-endpoint model host through this
-                site = getattr(env, "site", None)
-                value = executable(payload, site or default_site())
-            else:
-                value = executable(payload)
+            with metrics.span("worker.call"):
+                if rf.metadata.get("site_aware", False):
+                    # endpoint-scoped functions see where they run: the serving
+                    # tier resolves its per-endpoint model host through this
+                    site = getattr(env, "site", None)
+                    value = executable(payload, site or default_site())
+                else:
+                    value = executable(payload)
             if getattr(env, "spill_store", None) and env.spill_threshold:
                 # result spill: oversized result leaves stay in the object
                 # store near where they were computed; only refs travel the
                 # result path back through the fabric
                 from .datastore import get_store, spill_payload
 
-                store = get_store(env.spill_store)
-                value, _ = spill_payload(value, store, env.spill_threshold)
+                with metrics.span("data.spill"):
+                    store = get_store(env.spill_store)
+                    value, _ = spill_payload(value, store, env.spill_threshold)
             if rf.metadata.get("serialize_result", True):
                 # wire-faithful: results cross the executor/manager boundary as
                 # bytes; deserialized once at the service edge.
-                value = serializer.unpackb(serializer.packb(value))
+                with metrics.span("worker.repack"):
+                    value = serializer.unpackb(serializer.packb(value))
             env.timestamps.exec_end = time.monotonic()
             return TaskResult(
                 envelope=env, value=value, worker_id=self.worker_id,

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.containers import ResourceSpec
+from ..core.metrics import MetricsRegistry
 from ..models.model import Model
 from . import kv_cache
 
@@ -78,6 +79,11 @@ class DecodeCoalescer:
     every currently-active session has arrived), then runs ``step_fn`` over
     the merged slot set while followers block on their own result. Exactly
     one kernel invocation serves the whole batch.
+
+    Each batch run is counted in `metrics`: ``serving.decode_batches``, its
+    size in ``serving.merged_per_step``, and why the leader stopped waiting
+    in ``serving.window_full`` (every active session arrived) or
+    ``serving.window_expired`` (the window ran out).
     """
 
     def __init__(
@@ -85,15 +91,15 @@ class DecodeCoalescer:
         step_fn: Callable[[List[int]], Dict[int, int]],
         window_s: float = 0.003,
         target_fn: Optional[Callable[[], int]] = None,
+        metrics: Optional[MetricsRegistry] = None,
     ):
         self._step = step_fn
         self.window_s = window_s
         self._target = target_fn or (lambda: 1)
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
         self._cond = threading.Condition()
         self._waiting: Dict[int, _PendingDecode] = {}
         self._leading = False
-        self.batches = 0
-        self.merged = 0
 
     def submit(self, slot: int) -> int:
         mine = _PendingDecode()
@@ -102,19 +108,22 @@ class DecodeCoalescer:
             self._cond.notify_all()
             # follower path: somebody is already leading — wait for them to
             # take (and serve) our slot, or for leadership to free up
-            while self._leading and not mine.event.is_set():
-                self._cond.wait(timeout=self.window_s)
+            if self._leading:
+                with self.metrics.span("serving.coalesce_follow"):
+                    while self._leading and not mine.event.is_set():
+                        self._cond.wait(timeout=self.window_s)
             if mine.event.is_set():
                 return self._collect(mine)
             self._leading = True
         try:
             deadline = time.monotonic() + self.window_s
-            with self._cond:
+            with self._cond, self.metrics.span("serving.coalesce_lead"):
                 while (
                     len(self._waiting) < max(1, self._target())
                     and (remaining := deadline - time.monotonic()) > 0
                 ):
                     self._cond.wait(timeout=remaining)
+                full = len(self._waiting) >= max(1, self._target())
                 batch = dict(self._waiting)
                 self._waiting.clear()
             try:
@@ -126,9 +135,12 @@ class DecodeCoalescer:
                         pending.event.set()
                     self._cond.notify_all()
                 raise
+            self.metrics.counter("serving.decode_batches").inc()
+            self.metrics.histogram("serving.merged_per_step").observe(len(batch))
+            self.metrics.counter(
+                "serving.window_full" if full else "serving.window_expired"
+            ).inc()
             with self._cond:
-                self.batches += 1
-                self.merged += len(batch)
                 for s, pending in batch.items():
                     pending.token = tokens[s]
                     pending.event.set()
@@ -194,11 +206,10 @@ class ModelHost:
         if cache_bytes_budget is not None:
             max_sessions = max(1, min(max_sessions, cache_bytes_budget // per_seq))
         self.n_slots = int(max_sessions)
-        self.metrics = metrics
-        if metrics is not None:
-            metrics.gauge("serving.cache_bytes").set(
-                kv_cache.cache_bytes(self.cfg, self.n_slots, max_len)
-            )
+        self.metrics = metrics if metrics is not None else MetricsRegistry()
+        self.metrics.gauge("serving.cache_bytes").set(
+            kv_cache.cache_bytes(self.cfg, self.n_slots, max_len)
+        )
 
         self._prefill = jax.jit(model.prefill)
         self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
@@ -215,6 +226,7 @@ class ModelHost:
                 self._batched_step,
                 window_s=window_s,
                 target_fn=lambda: len(self.sessions),
+                metrics=self.metrics,
             )
         else:
             # each session decodes in a private batch-1 cache of max_len
@@ -229,8 +241,7 @@ class ModelHost:
 
     # -- metrics helpers ---------------------------------------------------
     def _count(self, name: str, n: int = 1) -> None:
-        if self.metrics is not None:
-            self.metrics.counter(name).inc(n)
+        self.metrics.counter(name).inc(n)
 
     # -- session lifecycle -------------------------------------------------
     def prefill(self, session: str, tokens) -> int:
@@ -259,13 +270,15 @@ class ModelHost:
             batch["frames"] = np.zeros(
                 (1, self.cfg.enc_seq, self.cfg.d_model), np.float32
             )
-        logits, seq_cache = self._prefill(self.params, batch)
-        first = int(jnp.argmax(logits[0]))
+        with self.metrics.span("serving.prefill"):
+            logits, seq_cache = self._prefill(self.params, batch)
+            first = int(jnp.argmax(logits[0]))
         if not self.batching:
             seq_cache = self._to_decode_cache(seq_cache)
         with self._lock:
             if self.batching:
-                self.cache = self._insert(self.cache, seq_cache, slot)
+                with self.metrics.span("serving.insert"):
+                    self.cache = self._insert(self.cache, seq_cache, slot)
                 self.slot_pos[slot] = len(tokens)
                 self.slot_last[slot] = first
                 seq_cache = None
@@ -275,8 +288,7 @@ class ModelHost:
             n_active = len(self.sessions)
         self._count("serving.prefills")
         self._count("serving.tokens_generated")
-        if self.metrics is not None:
-            self.metrics.gauge("serving.sessions_active").set(n_active)
+        self.metrics.gauge("serving.sessions_active").set(n_active)
         return first
 
     def decode(self, session: str, tokens) -> Tuple[int, bool]:
@@ -314,8 +326,7 @@ class ModelHost:
             if st is not None:
                 self._free.add(st.slot)
             n_active = len(self.sessions)
-        if self.metrics is not None:
-            self.metrics.gauge("serving.sessions_active").set(n_active)
+        self.metrics.gauge("serving.sessions_active").set(n_active)
         return st is not None
 
     # -- batched decode kernel --------------------------------------------
@@ -327,22 +338,19 @@ class ModelHost:
         — byte-identical values their own next step overwrites again, which
         is why batching is gated to attention families.
         """
-        with self._lock:
+        with self._lock, self.metrics.span("serving.step"):
             tok = self.slot_last[:, None].copy()
             pos_vec = jnp.asarray(self.slot_pos)
             logits, self.cache = self._decode(
                 self.params, jnp.asarray(tok), self.cache, pos_vec
             )
-            nt = np.asarray(jnp.argmax(logits, axis=-1))
+            with self.metrics.span("serving.readback"):
+                nt = np.asarray(jnp.argmax(logits, axis=-1))
             out = {}
             for s in slots:
                 self.slot_last[s] = int(nt[s])
                 self.slot_pos[s] += 1
                 out[s] = int(nt[s])
-        self._count("serving.decode_batches")
-        if self.metrics is not None:
-            self.metrics.gauge("serving.batch_occupancy").set(len(slots))
-            self.metrics.histogram("serving.merged_per_step").observe(len(slots))
         return out
 
     def stats(self) -> dict:
@@ -352,8 +360,8 @@ class ModelHost:
                 "slots": self.n_slots,
                 "active": len(self.sessions),
                 "free": len(self._free),
-                "decode_batches": self.coalescer.batches if self.coalescer else 0,
-                "merged": self.coalescer.merged if self.coalescer else 0,
+                "decode_batches": self.metrics.counter("serving.decode_batches").value,
+                "merged": int(self.metrics.histogram("serving.merged_per_step").sum),
                 "cache": kv_cache.summarize(self.cfg, self.n_slots, self.max_len),
             }
 

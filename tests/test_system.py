@@ -13,6 +13,7 @@ from repro.core import (
     SCOPE_REGISTER_ENDPOINT,
     SCOPE_REGISTER_FUNCTION,
 )
+from repro.core.futures import Timestamps
 
 
 @pytest.fixture()
@@ -47,10 +48,25 @@ def test_latency_breakdown_monotonic(service):
     fut = service.run(fid, {"x": np.arange(2)})
     fut.result(10)
     b = fut.latency_breakdown()
-    assert set(b) == {"t_c", "t_w", "t_m", "t_e", "total"}
+    assert set(b) == {"t_c", "t_w", "t_m", "t_e", "total", "t_q", "t_p", "t_r"}
     assert all(v >= 0 for v in b.values())
     assert b["total"] >= b["t_e"]
     assert abs(b["total"] - sum(b[k] for k in ("t_c", "t_w", "t_m", "t_e"))) < 1e-6
+    # t_m splits into the endpoint queue and the worker pickup
+    assert b["t_q"] + b["t_p"] == b["t_m"]
+
+
+@pytest.mark.parametrize("dispatched", [2.0, 0.0])
+def test_latency_breakdown_splits_manager_time(dispatched):
+    """t_m = t_q + t_p whether or not the endpoint stamped its dispatch (a
+    task handed straight to an executor has no endpoint queue)."""
+    ts = Timestamps(client_submit=0.5, service_in=0.75, endpoint_in=1.0,
+                    dispatched=dispatched, exec_start=3.5, exec_end=4.0,
+                    result_ready=4.25)
+    b = ts.breakdown()
+    assert b["t_q"] + b["t_p"] == b["t_m"] == 2.5
+    assert b["t_q"] == (1.0 if dispatched else 0.0)
+    assert b["t_r"] == 0.25
 
 
 def test_map_many_tasks(service):

@@ -24,7 +24,7 @@ DOC_FILES = sorted(
 METRIC_PREFIXES = (
     "service.", "forwarder.", "endpoint.", "executor.", "warming.",
     "autoscaler.", "workflow.", "trigger.", "container.", "journal.",
-    "data.", "predictor.", "fair.", "serving.",
+    "data.", "predictor.", "fair.", "serving.", "telemetry.", "worker.",
 )
 
 # [text](target) — excluding images; target split from any #anchor / title
