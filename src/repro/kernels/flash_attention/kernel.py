@@ -6,8 +6,13 @@ the MXU sees hardware-aligned (128-default) matmul tiles; q_offset / kv_len
 arrive via scalar prefetch (SMEM). The S^2 score matrix never touches HBM —
 this is the kernel the roofline memory model assumes on the TPU target.
 
-Validated against ref.mha_reference in interpret mode (CPU) by
-tests/test_kernels_flash.py across shape/dtype/causal/GQA sweeps.
+Decode has a kernel of its own (`decode_attention_pallas`): one query row
+per slot, a cache length per slot, and a memory-bound loop over the cache,
+so all slots share one grid of only their live K/V blocks.
+
+Validated against ref.mha_reference / ref.decode_attention_reference in
+interpret mode (CPU) by tests/test_kernels_flash.py across shape/dtype/
+causal/GQA sweeps.
 """
 from __future__ import annotations
 
@@ -19,6 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .ops import decode_block_k
 from .ref import NEG_INF
 
 
@@ -168,16 +174,118 @@ def flash_attention_pallas(
     return jnp.moveaxis(out, 1, 2)
 
 
+def _decode_kernel(
+    lens_ref,     # scalar prefetch: (B,) int32 valid cache positions per slot
+    steps_ref,    # scalar prefetch: (B * n_k,) int32 slot * n_k + k-block, live steps first
+    q_ref,        # (1, 1, H, hd)
+    k_ref,        # (1, block_k, KV, hd)
+    v_ref,        # (1, block_k, KV, dv)
+    o_ref,        # (1, 1, H, dv)
+    m_ref,        # (H, 1) f32 VMEM scratch
+    l_ref,        # (H, 1) f32
+    acc_ref,      # (H, dv) f32
+    *,
+    scale: float,
+    block_k: int,
+    n_k_blocks: int,
+    group: int,
+    ragged_tail: bool,
+):
+    step = steps_ref[pl.program_id(0)]
+    ik = step % n_k_blocks
+    kv_len = lens_ref[step // n_k_blocks]
+
+    @pl.when(ik == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    _, bk, KV, _ = k_ref.shape
+    # one row per (position, kv head): every query head scores every row,
+    # and the mask keeps its own kv head's rows at live positions
+    q = q_ref[0, 0].astype(jnp.float32)                                 # (H, hd)
+    k = k_ref[0].astype(jnp.float32).reshape(bk * KV, -1)               # (bk*KV, hd)
+    v = v_ref[0].astype(jnp.float32).reshape(bk * KV, -1)               # (bk*KV, dv)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * scale                                                           # (H, bk*KV)
+    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    live = (row % KV == head // group) & (ik * block_k + row // KV < kv_len)
+    s = jnp.where(live, s, NEG_INF)
+    if ragged_tail:  # the cache's last block runs past its end: those rows are not data
+        row_v = jax.lax.broadcasted_iota(jnp.int32, (bk * KV, 1), 0)
+        v = jnp.where(ik * block_k + row_v // KV < kv_len, v, 0.0)
+
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    )
+    m_ref[...] = m_new
+
+    @pl.when((ik + 1) * block_k >= kv_len)
+    def _finalize():
+        o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
 def decode_attention_pallas(q, k_cache, v_cache, pos, *, scale=None, interpret=False):
-    """Single-token attention: the flash kernel with Sq=1 per (batch, head)
-    and kv_len = pos + 1 (scalar, or per-row via vmap)."""
-    if jnp.ndim(pos) == 0:
-        return flash_attention_pallas(
-            q, k_cache, v_cache, causal=False, kv_len=pos + 1, scale=scale,
-            interpret=interpret,
-        )
-    fn = lambda qb, kb, vb, pb: flash_attention_pallas(
-        qb[None], kb[None], vb[None], causal=False, kv_len=pb + 1, scale=scale,
+    """Single-token attention for every slot in one kernel.
+
+    q (B, 1, H, hd); k_cache (B, S, KV, hd); v_cache (B, S, KV, dv), dv may
+    differ from hd (MLA's latent decode); pos scalar or (B,): slot b attends
+    to cache positions 0..pos[b]. K/V blocks are read from the cache as it
+    lies, and only the live ones: the grid has one step per (slot, k-block)
+    below the slot's length, slot by slot, sum(cdiv(pos + 1, block_k))
+    steps in all, so an idle slot costs one block and one step.
+    """
+    B, _, H, hd = q.shape
+    _, S, KV, dv = v_cache.shape
+    assert H % KV == 0, (H, KV)
+    scale = scale if scale is not None else hd ** -0.5
+    block_k = decode_block_k(S, KV, max(hd, dv))
+    n_k = pl.cdiv(S, block_k)
+    lens = jnp.broadcast_to(jnp.asarray(pos, jnp.int32) + 1, (B,))
+    # step i -> (slot, k-block): slots in order, each over its live blocks
+    n_live = (lens + block_k - 1) // block_k
+    ends = jnp.cumsum(n_live)
+    i = jnp.arange(B * n_k, dtype=jnp.int32)
+    slot = jnp.minimum(jnp.sum(i[:, None] >= ends[None, :], axis=1, dtype=jnp.int32), B - 1)
+    steps = slot * n_k + i - (ends - n_live)[slot]
+
+    def kv_index(i, lens, steps):  # noqa: ARG001 — grid ids first, scalar refs last
+        return (steps[i] // n_k, steps[i] % n_k, 0, 0)
+
+    def slot_index(i, lens, steps):  # noqa: ARG001
+        return (steps[i] // n_k, 0, 0, 0)
+
+    kernel = functools.partial(
+        _decode_kernel, scale=scale, block_k=block_k, n_k_blocks=n_k,
+        group=H // KV, ragged_tail=S % block_k != 0,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(ends[-1],),
+        in_specs=[
+            pl.BlockSpec((1, 1, H, hd), slot_index),
+            pl.BlockSpec((1, block_k, KV, hd), kv_index),
+            pl.BlockSpec((1, block_k, KV, dv), kv_index),
+        ],
+        out_specs=pl.BlockSpec((1, 1, H, dv), slot_index),
+        scratch_shapes=[
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, dv), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, 1, H, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )[0]
-    return jax.vmap(fn)(q, k_cache, v_cache, pos)
+    )(lens, steps, q, k_cache, v_cache)

@@ -12,6 +12,22 @@ import jax
 from . import ref
 
 
+# f32 bytes of one (block_k, KV, hd) K/V block the decode kernel may hold.
+# On a TPU v5e at qwen1.5-0.5b's widths (32 slots x 1536) 128-position
+# blocks were as fast as 256 and 512 with every slot full, and faster with
+# few live positions: a slot's last block is read whole, live or not.
+DECODE_BLOCK_BYTES = 512 * 1024
+
+
+def decode_block_k(seq_len: int, n_kv_heads: int, head_dim: int) -> int:
+    """Cache positions per block of the decode kernel: the largest of 512,
+    256 and 128 whose float32 (block, KV, hd) block fits DECODE_BLOCK_BYTES
+    (128 regardless), and never more than the cache holds."""
+    per_pos = n_kv_heads * head_dim * 4
+    block = next((b for b in (512, 256) if b * per_pos <= DECODE_BLOCK_BYTES), 128)
+    return min(block, seq_len)
+
+
 def _default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 

@@ -42,6 +42,7 @@ import numpy as np
 
 from ..core.containers import ResourceSpec
 from ..core.metrics import MetricsRegistry
+from ..kernels.flash_attention.ops import decode_block_k
 from ..models.model import Model
 from . import kv_cache
 
@@ -221,6 +222,13 @@ class ModelHost:
         if batching:
             self.cache, _ = model.init_cache(self.n_slots, max_len)
             self.slot_pos = np.zeros(self.n_slots, np.int32)
+            # the decode kernel's K/V block over this cache (MLA attends
+            # over one latent head of kv_lora + rope)
+            if self.cfg.mla is not None:
+                kv, width = 1, self.cfg.mla.kv_lora_rank + self.cfg.mla.qk_rope_dim
+            else:
+                kv, width = self.cfg.n_kv_heads, self.cfg.hd
+            self.block_k = decode_block_k(max_len, kv, width)
             self.slot_last = np.zeros(self.n_slots, np.int32)
             self.coalescer = DecodeCoalescer(
                 self._batched_step,
@@ -337,8 +345,15 @@ class ModelHost:
         not in `slots` rewrite their current position with their last token
         — byte-identical values their own next step overwrites again, which
         is why batching is gated to attention families.
+
+        Per step, ``serving.kv_blocks_read`` counts the K/V blocks the decode
+        kernel reads per layer (each slot's, up to its position) and
+        ``serving.kv_blocks_cached`` the blocks the cache holds.
         """
         with self._lock, self.metrics.span("serving.step"):
+            bk = self.block_k
+            self._count("serving.kv_blocks_read", int(((self.slot_pos + bk) // bk).sum()))
+            self._count("serving.kv_blocks_cached", self.n_slots * -(-self.max_len // bk))
             tok = self.slot_last[:, None].copy()
             pos_vec = jnp.asarray(self.slot_pos)
             logits, self.cache = self._decode(

@@ -4,16 +4,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # property-based cases skip without the dev extra
-    from _hypothesis_stub import given, settings, st
-
 from repro.kernels.flash_attention import ref
 from repro.kernels.flash_attention.kernel import (
     decode_attention_pallas,
     flash_attention_pallas,
 )
+from repro.kernels.flash_attention.ops import decode_block_k
 
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
 
@@ -89,33 +85,49 @@ def test_flash_q_offset_decode_window(key):
     np.testing.assert_allclose(tail, full[:, 48:], rtol=2e-5, atol=2e-5)
 
 
-@given(
-    pos=st.integers(min_value=0, max_value=47),
-    kv=st.sampled_from([1, 2, 4]),
-)
-@settings(max_examples=12, deadline=None)
-def test_decode_kernel_property(pos, kv):
-    key = jax.random.PRNGKey(pos)
+def _decode_case(key, B, S, H, KV, hd, dv, dtype):
     ks = jax.random.split(key, 3)
-    B, S, H, hd = 2, 48, 4, 16
-    q = _rand(ks[0], (B, 1, H, hd), jnp.float32)
-    kc = _rand(ks[1], (B, S, kv, hd), jnp.float32)
-    vc = _rand(ks[2], (B, S, kv, hd), jnp.float32)
-    out = decode_attention_pallas(q, kc, vc, jnp.int32(pos), interpret=True)
-    expected = ref.decode_attention_reference(q, kc, vc, jnp.int32(pos))
-    np.testing.assert_allclose(out, expected, rtol=3e-5, atol=3e-5)
+    return (_rand(ks[0], (B, 1, H, hd), dtype), _rand(ks[1], (B, S, KV, hd), dtype),
+            _rand(ks[2], (B, S, KV, dv), dtype))
 
 
-def test_decode_kernel_vector_positions(key):
-    ks = jax.random.split(key, 3)
-    B, S, KV, H, hd = 3, 32, 2, 4, 16
-    q = _rand(ks[0], (B, 1, H, hd), jnp.float32)
-    kc = _rand(ks[1], (B, S, KV, hd), jnp.float32)
-    vc = _rand(ks[2], (B, S, KV, hd), jnp.float32)
-    pos = jnp.array([3, 17, 31], jnp.int32)
+def _edges(S, KV, width):
+    """Positions 0, bk-1, bk and S-1 for the block the kernel picks."""
+    bk = decode_block_k(S, KV, width)
+    assert S > bk
+    return jnp.array([0, bk - 1, bk, S - 1], jnp.int32)
+
+
+# (B, S, H, KV, hd, dv, pos): pos None means the block edges of _edges
+DECODE_CASES = {
+    "mha_edges_ragged": (4, 300, 8, 8, 128, 128, None),     # bk 128, S % bk = 44
+    "gqa2_edges_ragged": (4, 600, 8, 4, 128, 128, None),    # bk 256
+    "gqa4_edges_ragged": (4, 700, 8, 2, 64, 64, None),      # bk 512
+    "gqa7_edges": (4, 1024, 14, 2, 64, 64, None),           # qwen2-0.5b's heads
+    "mla_edges_ragged": (4, 400, 8, 1, 288, 256, None),     # latent: dk != dv
+    "mha_scalar_pos": (2, 300, 8, 8, 128, 128, 200),
+    "short_cache_scalar": (2, 48, 4, 2, 16, 16, 0),         # one block, S < bk
+    "short_cache_mha_scalar": (2, 48, 4, 4, 16, 16, 23),
+    "short_cache_vector": (3, 32, 4, 2, 16, 16, [3, 17, 31]),
+    "mqa_short_vector": (2, 48, 4, 1, 16, 16, [47, 12]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", list(DECODE_CASES))
+def test_decode_kernel_matches_ref(case, dtype, key):
+    B, S, H, KV, hd, dv, pos = DECODE_CASES[case]
+    q, kc, vc = _decode_case(key, B, S, H, KV, hd, dv, dtype)
+    if pos is None:
+        pos = _edges(S, KV, max(hd, dv))
+    pos = jnp.asarray(pos, jnp.int32)
     out = decode_attention_pallas(q, kc, vc, pos, interpret=True)
     expected = ref.decode_attention_reference(q, kc, vc, pos)
-    np.testing.assert_allclose(out, expected, rtol=3e-5, atol=3e-5)
+    assert out.shape == (B, 1, H, dv)
+    np.testing.assert_allclose(
+        out.astype(jnp.float32), expected.astype(jnp.float32),
+        rtol=TOL[dtype], atol=TOL[dtype],
+    )
 
 
 def test_causality_property(key):

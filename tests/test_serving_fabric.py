@@ -226,6 +226,26 @@ def test_cache_bytes_admission_control(small_model):
     assert metrics.gauge("serving.cache_bytes").value == 2 * per_seq
 
 
+def test_kv_block_counters_match_hand_count(small_model):
+    """Each batched step counts the decode kernel's K/V blocks per layer:
+    every slot's ceil((pos + 1) / block), idle slots included, against the
+    blocks the cache holds."""
+    model, params = small_model
+    metrics = MetricsRegistry()
+    host = ModelHost(model, params, max_len=1100, max_sessions=3, metrics=metrics)
+    # 4 KV heads of 16 in float32: 256 bytes a position, so 512-position blocks
+    assert host.block_k == 512
+    rng = np.random.default_rng(5)
+    host.prefill("long", rng.integers(0, model.cfg.vocab, 1022))
+    host.prefill("short", rng.integers(0, model.cfg.vocab, 5))
+    for _ in range(3):  # "long" decodes at 1022, 1023, 1024; the others stay
+        host.decode("long", [])
+    # long: 2, 2, 3 blocks; short: 1 each step; the unused slot: 1 each step
+    assert metrics.counter("serving.kv_blocks_read").value == 7 + 3 + 3
+    # 3 steps x 3 slots x ceil(1100 / 512) blocks
+    assert metrics.counter("serving.kv_blocks_cached").value == 27
+
+
 # ------------------------------------------------------------- coalescer
 def test_decode_coalescer_merges_concurrent_submits():
     calls = []

@@ -8,6 +8,7 @@ the program. Widths are qwen1.5-0.5b's (16 heads of 64) and mamba2-2.7b's
 (80 SSD heads of 64, state 128, chunk 256).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,6 +68,26 @@ def test_vector_pos_decode_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "minicpm3-4b"])
+def test_decode_kernel_compiles_for_v5e_at_gqa_and_mla_widths(one_chip, arch):
+    """GQA (qwen2-0.5b: 14 heads over 2) and MLA's latent decode
+    (minicpm3-4b: 40 heads over one latent of 256 + 32 rope, values 256)."""
+    cfg = get_config(arch)
+    if cfg.mla is not None:
+        kv, dk, dv = 1, cfg.mla.kv_lora_rank + cfg.mla.qk_rope_dim, cfg.mla.kv_lora_rank
+    else:
+        kv, dk, dv = cfg.n_kv_heads, cfg.hd, cfg.hd
+    B, S = 32, 1536
+    text = _compiled_text(
+        flash.decode_attention_pallas,
+        _spec(one_chip, (B, 1, cfg.n_heads, dk)),
+        _spec(one_chip, (B, S, kv, dk)),
+        _spec(one_chip, (B, S, kv, dv)),
+        _spec(one_chip, (B,), jnp.int32),
+    )
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
 def test_ssd_compiles_for_v5e_at_mamba2_widths(one_chip):
     full = get_config("mamba2-2.7b")
     s = full.ssm
@@ -108,3 +129,68 @@ def test_full_width_decode_step_compiles_for_v5e(one_chip, monkeypatch):
     assert mem.peak_memory_in_bytes >= mem.argument_size_in_bytes > 1.5e9
     resident = analyze_compiled(compiled, n_chips=1)["memory"]["resident_bytes"]
     assert resident == mem.peak_memory_in_bytes
+
+
+def _computations(text):
+    """Compiled HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line.strip())
+    return comps
+
+
+def _calls(line):
+    return re.findall(r"(?:calls|body|condition|to_apply)=%([\w.\-]+)", line)
+
+
+def _holds(comps, comp, needle, seen=()):
+    """Whether computation `comp`, or one it calls, has a line with `needle`."""
+    return any(needle in line or any(c not in seen and _holds(comps, c, needle, seen + (comp,))
+                                     for c in _calls(line))
+               for line in comps[comp])
+
+
+def test_served_decode_step_reads_cache_in_one_kernel(one_chip, monkeypatch):
+    """The served qwen1.5-0.5b decode step at serve.chat's 32 slots x 1536
+    positions: one decode kernel per layer body for every slot (no loop over
+    slots), fed K/V with no copy into its layout, in no more temporary
+    memory than the per-slot kernel needed (6.04 GB)."""
+    monkeypatch.setattr(attn_ops, "_default_impl", lambda: "pallas")
+    cfg = get_config("qwen1.5-0.5b")
+    model = Model(cfg)
+    on_chip = lambda s: _spec(one_chip, s.shape, s.dtype)  # noqa: E731
+    params = jax.tree.map(on_chip, model.abstract_params())
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: model.init_cache(32, 1536)[0]))
+    compiled = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+        params,
+        _spec(one_chip, (32, 1), jnp.int32),
+        cache,
+        _spec(one_chip, (32,), jnp.int32),
+    ).compile()
+    comps = _computations(compiled.as_text())
+    kernel = 'custom_call_target="tpu_custom_call"'
+    sites = [(c, line) for c, lines in comps.items() for line in lines if kernel in line]
+    assert len(sites) == 1, [line[:120] for _, line in sites]
+    # the one while is the scan over layers, and its body holds the kernel
+    whiles = [line for lines in comps.values() for line in lines if " while(" in line]
+    assert len(whiles) == 1, [w[:120] for w in whiles]
+    body = re.search(r"body=%([\w.\-]+)", whiles[0]).group(1)
+    assert _holds(comps, body, kernel)
+
+    comp, call = sites[0]
+    defs = {re.match(r"(?:ROOT )?%(\S+) = ", line).group(1): line for line in comps[comp]}
+    operands = re.sub(r"/\*.*?\*/", "", re.search(r"custom-call\(([^)]*)\)", call).group(1))
+    operands = operands.split(", ")
+    for name in operands[-2:]:                      # K and V come last
+        line = defs[name.lstrip("%")]
+        while " bitcast(" in line:
+            line = defs[re.search(r" bitcast\(%([\w.\-]+)\)", line).group(1)]
+        assert not re.match(r"(ROOT )?%copy", line), line[:160]
+        assert " copy(" not in line and " copy-done(" not in line, line[:160]
+
+    assert compiled.memory_analysis().temp_size_in_bytes <= 6.04e9
