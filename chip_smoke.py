@@ -162,7 +162,7 @@ def reference_logits(model: Model, params, tokens: np.ndarray) -> np.ndarray:
 
 
 def check_logits(host, params, tokens: np.ndarray) -> None:
-    logits, _ = host._prefill(params, {"tokens": jnp.asarray(tokens)})
+    logits = host._prefill(params, {"tokens": jnp.asarray(tokens)})[0]
     got = np.asarray(logits.astype(jnp.float32))
     want = reference_logits(host.model, params, tokens)
     check(got.shape == want.shape == (1, host.cfg.vocab),
@@ -194,7 +194,7 @@ def check_decode(client, host, params, endpoint_id: str, prompts) -> None:
                 s.step(timeout=600)
         with host._lock:
             check(len(host.sessions) == host.n_slots, "every slot is live")
-            logits, host.cache = host._decode(
+            logits, host.cache, _ = host._decode(
                 params, jnp.asarray(host.slot_last[:, None]), host.cache,
                 jnp.asarray(host.slot_pos),
             )

@@ -1,13 +1,14 @@
-"""Assigned-architecture configs (10 archs) + shape sets.
+"""Assigned-architecture configs (11 archs) + shape sets.
 
 ``get_config(arch_id)`` returns the exact published config;
 ``get_reduced(arch_id)`` the smoke-test reduction of the same family.
 """
-from .base import ARCHS, MLAConfig, ModelConfig, MoEConfig, SSMConfig  # noqa: F401
+from .base import ARCHS, MLAConfig, ModelConfig, MoEConfig, SSMConfig, YarnConfig  # noqa: F401
 
 # importing each module populates ARCHS
 from . import (  # noqa: F401,E402
     deepseek_67b,
+    deepseek_v2_lite,
     internvl2_26b,
     mamba2_2_7b,
     minicpm3_4b,

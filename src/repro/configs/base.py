@@ -17,15 +17,43 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_dtype: str = "float32"
     norm_topk_prob: bool = True
+    shared_gate: bool = True        # Qwen2-MoE's sigmoid gate on the shared
+    #                                 experts' output; DeepSeek's have none
+    dropless: bool = False          # every routed (token, choice) is computed:
+    #                                 no capacity, so no token is dropped
+    # the routed experts this chip holds, [first_held, first_held + n_held)
+    # of the n_experts the router scores (expert parallelism: one chip's
+    # share); n_held 0 holds every expert
+    first_held: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int
+    q_lora_rank: Optional[int]      # None: queries projected directly (D -> H x qk)
     kv_lora_rank: int
     qk_nope_dim: int
     qk_rope_dim: int
     v_head_dim: int
+    rope_interleaved: bool = False  # DeepSeek-V2 rotates adjacent pairs of
+    #                                 the rope dims (its modeling code
+    #                                 de-interleaves them before rotate-half)
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN RoPE scaling, as DeepSeek-V2's ``rope_scaling`` gives it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclass(frozen=True)
@@ -58,9 +86,13 @@ class ModelConfig:
     qkv_bias: bool = False
     qk_norm: bool = False           # Qwen3-style per-head RMSNorm on q,k
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnConfig] = None
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    # moe family: the first layers keep a dense SwiGLU MLP of width d_ff
+    # (DeepSeek's first_k_dense_replace); the rest are expert layers
+    first_dense_layers: int = 0
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     # encoder-decoder (whisper): encoder depth and fixed frame count (stub frontend)
@@ -109,9 +141,13 @@ class ModelConfig:
             if self.mla is not None:
                 m = self.mla
                 qk = m.qk_nope_dim + m.qk_rope_dim
+                if m.q_lora_rank is None:
+                    q = D * self.n_heads * qk
+                else:
+                    q = D * m.q_lora_rank + m.q_lora_rank + m.q_lora_rank * self.n_heads * qk
                 return (
-                    D * m.q_lora_rank + m.q_lora_rank * self.n_heads * qk
-                    + D * (m.kv_lora_rank + m.qk_rope_dim)
+                    q
+                    + D * (m.kv_lora_rank + m.qk_rope_dim) + m.kv_lora_rank
                     + m.kv_lora_rank * self.n_heads * (m.qk_nope_dim + m.v_head_dim)
                     + self.n_heads * m.v_head_dim * D
                 )
@@ -122,11 +158,11 @@ class ModelConfig:
 
         def moe_params(active: bool) -> int:
             m = self.moe
-            e = m.top_k if active else m.n_experts
+            e = min(m.top_k, m.held) if active else m.held
             p = D * m.n_experts  # router
             p += e * 3 * D * m.d_ff_expert
             if m.n_shared_experts:
-                p += 3 * D * m.d_ff_shared + D  # shared experts + gate
+                p += 3 * D * m.d_ff_shared + (D if m.shared_gate else 0)
             return p
 
         def ssm_params() -> int:
@@ -147,7 +183,9 @@ class ModelConfig:
             if self.family == "vlm":
                 total += D * D  # patch projection stub
         elif self.family == "moe":
-            total += L * (attn_params() + moe_params(active_only) + 2 * D)
+            n_dense = self.first_dense_layers
+            total += L * (attn_params() + 2 * D)
+            total += n_dense * mlp_params(self.d_ff) + (L - n_dense) * moe_params(active_only)
         elif self.family == "ssm":
             total += L * (ssm_params() + D)
         elif self.family == "hybrid":

@@ -32,9 +32,9 @@ def _flash_kernel(
     meta_ref,     # scalar prefetch: (2,) int32 [q_offset, kv_len]
     q_ref,        # (1, block_q, hd)
     k_ref,        # (1, block_k, hd)
-    v_ref,        # (1, block_k, hd)
-    o_ref,        # (1, block_q, hd)
-    acc_ref,      # (block_q, hd) f32 VMEM scratch
+    v_ref,        # (1, block_k, dv)
+    o_ref,        # (1, block_q, dv)
+    acc_ref,      # (block_q, dv) f32 VMEM scratch
     m_ref,        # (block_q, 1) f32
     l_ref,        # (block_q, 1) f32
     *,
@@ -123,6 +123,7 @@ def flash_attention_pallas(
 ) -> jnp.ndarray:
     B, Sq, H, hd = q.shape
     _, Skv, KV, _ = k.shape
+    dv = v.shape[-1]
     assert H % KV == 0, (H, KV)
     G = H // KV
     scale = scale if scale is not None else hd ** -0.5
@@ -131,7 +132,7 @@ def flash_attention_pallas(
 
     qt = _pad_to(jnp.moveaxis(q, 2, 1).reshape(B * H, Sq, hd), 1, block_q)
     kt = _pad_to(jnp.moveaxis(k, 2, 1).reshape(B * KV, Skv, hd), 1, block_k)
-    vt = _pad_to(jnp.moveaxis(v, 2, 1).reshape(B * KV, Skv, hd), 1, block_k)
+    vt = _pad_to(jnp.moveaxis(v, 2, 1).reshape(B * KV, Skv, dv), 1, block_k)
     Sq_p, Skv_p = qt.shape[1], kt.shape[1]
     n_q, n_k = Sq_p // block_q, Skv_p // block_k
 
@@ -152,11 +153,11 @@ def flash_attention_pallas(
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, iq, ik, meta: (bh, iq, 0)),
             pl.BlockSpec((1, block_k, hd), kv_index),
-            pl.BlockSpec((1, block_k, hd), kv_index),
+            pl.BlockSpec((1, block_k, dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, block_q, hd), lambda bh, iq, ik, meta: (bh, iq, 0)),
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda bh, iq, ik, meta: (bh, iq, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_q, hd), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
@@ -164,13 +165,13 @@ def flash_attention_pallas(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B * H, Sq_p, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(meta, qt, kt, vt)
-    out = out[:, :Sq].reshape(B, H, Sq, hd)
+    out = out[:, :Sq].reshape(B, H, Sq, dv)
     return jnp.moveaxis(out, 1, 2)
 
 
@@ -179,18 +180,20 @@ def _decode_kernel(
     steps_ref,    # scalar prefetch: (B * n_k,) int32 slot * n_k + k-block, live steps first
     q_ref,        # (1, 1, H, hd)
     k_ref,        # (1, block_k, KV, hd)
-    v_ref,        # (1, block_k, KV, dv)
-    o_ref,        # (1, 1, H, dv)
-    m_ref,        # (H, 1) f32 VMEM scratch
-    l_ref,        # (H, 1) f32
-    acc_ref,      # (H, dv) f32
-    *,
+    *refs,        # [v_ref (1, block_k, KV, dv)], o_ref (1, 1, H, dv), then
+    #               scratch m_ref (H, 1), l_ref (H, 1), acc_ref (H, dv) f32
     scale: float,
     block_k: int,
     n_k_blocks: int,
     group: int,
     ragged_tail: bool,
+    dv: int,
+    v_in_k: bool,
 ):
+    if v_in_k:            # the values are the first dv columns of each key row
+        o_ref, m_ref, l_ref, acc_ref = refs
+    else:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     step = steps_ref[pl.program_id(0)]
     ik = step % n_k_blocks
     kv_len = lens_ref[step // n_k_blocks]
@@ -206,7 +209,10 @@ def _decode_kernel(
     # and the mask keeps its own kv head's rows at live positions
     q = q_ref[0, 0].astype(jnp.float32)                                 # (H, hd)
     k = k_ref[0].astype(jnp.float32).reshape(bk * KV, -1)               # (bk*KV, hd)
-    v = v_ref[0].astype(jnp.float32).reshape(bk * KV, -1)               # (bk*KV, dv)
+    if v_in_k:
+        v = k[:, :dv]                                                   # (bk*KV, dv)
+    else:
+        v = v_ref[0].astype(jnp.float32).reshape(bk * KV, -1)           # (bk*KV, dv)
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale                                                           # (H, bk*KV)
@@ -233,18 +239,23 @@ def _decode_kernel(
         o_ref[0, 0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def decode_attention_pallas(q, k_cache, v_cache, pos, *, scale=None, interpret=False):
+def decode_attention_pallas(q, k_cache, v_cache, pos, *, scale=None, dv=None,
+                            interpret=False):
     """Single-token attention for every slot in one kernel.
 
     q (B, 1, H, hd); k_cache (B, S, KV, hd); v_cache (B, S, KV, dv), dv may
-    differ from hd (MLA's latent decode); pos scalar or (B,): slot b attends
+    differ from hd; or v_cache None and the values the first `dv` columns of
+    each key row, read with it (MLA's latent decode: one latent head is the
+    key, and its first kv_lora columns the value); pos scalar or (B,): slot b attends
     to cache positions 0..pos[b]. K/V blocks are read from the cache as it
     lies, and only the live ones: the grid has one step per (slot, k-block)
     below the slot's length, slot by slot, sum(cdiv(pos + 1, block_k))
     steps in all, so an idle slot costs one block and one step.
     """
     B, _, H, hd = q.shape
-    _, S, KV, dv = v_cache.shape
+    _, S, KV, _ = k_cache.shape
+    v_in_k = v_cache is None
+    dv = dv if v_in_k else v_cache.shape[-1]
     assert H % KV == 0, (H, KV)
     scale = scale if scale is not None else hd ** -0.5
     block_k = decode_block_k(S, KV, max(hd, dv))
@@ -265,16 +276,20 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, scale=None, interpret=F
 
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_k=block_k, n_k_blocks=n_k,
-        group=H // KV, ragged_tail=S % block_k != 0,
+        group=H // KV, ragged_tail=S % block_k != 0, dv=dv, v_in_k=v_in_k,
     )
+    in_specs = [
+        pl.BlockSpec((1, 1, H, hd), slot_index),
+        pl.BlockSpec((1, block_k, KV, hd), kv_index),
+    ]
+    operands = (q, k_cache)
+    if not v_in_k:
+        in_specs.append(pl.BlockSpec((1, block_k, KV, dv), kv_index))
+        operands += (v_cache,)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(ends[-1],),
-        in_specs=[
-            pl.BlockSpec((1, 1, H, hd), slot_index),
-            pl.BlockSpec((1, block_k, KV, hd), kv_index),
-            pl.BlockSpec((1, block_k, KV, dv), kv_index),
-        ],
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, H, dv), slot_index),
         scratch_shapes=[
             pltpu.VMEM((H, 1), jnp.float32),
@@ -288,4 +303,4 @@ def decode_attention_pallas(q, k_cache, v_cache, pos, *, scale=None, interpret=F
         out_shape=jax.ShapeDtypeStruct((B, 1, H, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lens, steps, q, k_cache, v_cache)
+    )(lens, steps, *operands)

@@ -45,7 +45,8 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
 ):
-    """GQA attention. q (B,Sq,H,hd); k,v (B,Skv,KV,hd) -> (B,Sq,H,hd)."""
+    """GQA attention. q, k (B,Sq,H,hd), (B,Skv,KV,hd); v (B,Skv,KV,dv), dv
+    may differ from hd (MLA's prefill: qk 192, v 128) -> (B,Sq,H,dv)."""
     if impl == "auto":
         impl = _default_impl()
     if impl == "ref":
@@ -60,14 +61,18 @@ def flash_attention(
     )
 
 
-def decode_attention(q, k_cache, v_cache, pos, *, scale=None, impl: str = "auto"):
-    """Single-token attention against a cache; entries <= pos are valid."""
+def decode_attention(q, k_cache, v_cache, pos, *, scale=None, dv=None, impl: str = "auto"):
+    """Single-token attention against a cache; entries <= pos are valid.
+    ``v_cache=None`` with ``dv``: the values are the first `dv` columns of
+    each key row (MLA's latent is both key and value), read once."""
     if impl == "auto":
         impl = _default_impl()
     if impl == "ref":
+        if v_cache is None:
+            v_cache = k_cache[..., :dv]
         return ref.decode_attention_reference(q, k_cache, v_cache, pos, scale=scale)
     from . import kernel
 
     return kernel.decode_attention_pallas(
-        q, k_cache, v_cache, pos, scale=scale, interpret=(impl == "pallas_interpret")
+        q, k_cache, v_cache, pos, scale=scale, dv=dv, interpret=(impl == "pallas_interpret")
     )

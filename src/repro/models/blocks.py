@@ -2,7 +2,8 @@
 
 A "layer" here is the unit the model stack scans over. Families:
   dense | vlm : (MLA or GQA) attention + SwiGLU MLP
-  moe         : GQA attention + routed-expert FFN (+ shared experts)
+  moe         : (MLA or GQA) attention + routed-expert FFN (+ shared
+                experts); a config's first_dense_layers keep the SwiGLU MLP
   ssm         : Mamba2 block
   hybrid      : Mamba2 layers; the *shared* attention block lives in model.py
   encdec      : encoder layer (bidir attn + GELU MLP) and
@@ -27,7 +28,11 @@ def _residual_enter(h, cfg: ModelConfig):
 
 
 # ---------------------------------------------------------------- dense / moe
-def init_decoder_layer(key, cfg: ModelConfig):
+def _routed(cfg: ModelConfig, dense_ffn: bool) -> bool:
+    return cfg.family == "moe" and not dense_ffn
+
+
+def init_decoder_layer(key, cfg: ModelConfig, dense_ffn: bool = False):
     k1, k2 = jax.random.split(key)
     if cfg.mla is not None:
         attn_p, attn_s = mla.init_mla(k1, cfg)
@@ -35,7 +40,7 @@ def init_decoder_layer(key, cfg: ModelConfig):
         attn_p, attn_s = attention.init_attention(k1, cfg)
     n1, n1s = layers.init_rmsnorm(cfg.d_model)
     n2, n2s = layers.init_rmsnorm(cfg.d_model)
-    if cfg.family == "moe":
+    if _routed(cfg, dense_ffn):
         ffn_p, ffn_s = moe.init_moe(k2, cfg)
     else:
         ffn_p, ffn_s = layers.init_swiglu(k2, cfg.d_model, cfg.d_ff, layers.dtype_of(cfg))
@@ -45,9 +50,10 @@ def init_decoder_layer(key, cfg: ModelConfig):
 
 
 def decoder_layer(
-    p, h: jnp.ndarray, cfg: ModelConfig, positions: jnp.ndarray
-) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[tuple]]:
-    """Train/prefill. Returns (h, aux_loss, kv_for_cache)."""
+    p, h: jnp.ndarray, cfg: ModelConfig, positions: jnp.ndarray, dense_ffn: bool = False
+) -> Tuple[jnp.ndarray, jnp.ndarray, Optional[tuple], jnp.ndarray]:
+    """Train/prefill. Returns (h, aux_loss, kv_for_cache, routing counts
+    as ``moe.moe_layer`` gives them)."""
     h = _residual_enter(h, cfg)
     hn = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
     if cfg.mla is not None:
@@ -57,35 +63,36 @@ def decoder_layer(
             p["attn"], hn, cfg, positions=positions, causal=True, return_kv=True
         )
     h = h + a
+    f, aux, counts = _ffn(p, h, cfg, dense_ffn)
+    return h + f, aux, kv, counts
+
+
+def _ffn(p, h, cfg: ModelConfig, dense_ffn: bool):
     hn = layers.rmsnorm(h, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        f, aux = moe.moe_ffn(hn, p["ffn"], cfg)
-    else:
-        f, aux = layers.swiglu(hn, p["ffn"]), jnp.float32(0.0)
-    return h + f, aux, kv
+    if _routed(cfg, dense_ffn):
+        return moe.moe_layer(hn, p["ffn"], cfg)
+    # a layer with no routed experts: no choices, no expert rows
+    no_counts = (jnp.zeros(h.shape[0] * h.shape[1], jnp.int32), jnp.int32(0))
+    return layers.swiglu(hn, p["ffn"]), jnp.float32(0.0), no_counts
 
 
 def decoder_layer_decode(
-    p, h: jnp.ndarray, cache: dict, pos: jnp.ndarray, cfg: ModelConfig
-) -> Tuple[jnp.ndarray, dict]:
+    p, h: jnp.ndarray, cache: dict, pos: jnp.ndarray, cfg: ModelConfig,
+    dense_ffn: bool = False,
+) -> Tuple[jnp.ndarray, dict, jnp.ndarray]:
+    """One token per row. Returns (h, new_cache, routing counts)."""
     hn = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
     if cfg.mla is not None:
-        a, (ckv, krope) = mla.mla_attention_decode(
-            p["attn"], hn, cache["ckv"], cache["krope"], pos, cfg
-        )
-        new_cache = {"ckv": ckv, "krope": krope}
+        a, latent = mla.mla_attention_decode(p["attn"], hn, cache["latent"], pos, cfg)
+        new_cache = {"latent": latent}
     else:
         a, (k, v) = attention.self_attention_decode(
             p["attn"], hn, cache["k"], cache["v"], pos, cfg
         )
         new_cache = {"k": k, "v": v}
     h = h + a
-    hn = layers.rmsnorm(h, p["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        f, _ = moe.moe_ffn(hn, p["ffn"], cfg)
-    else:
-        f = layers.swiglu(hn, p["ffn"])
-    return h + f, new_cache
+    f, _, counts = _ffn(p, h, cfg, dense_ffn)
+    return h + f, new_cache, counts
 
 
 def init_decoder_cache(cfg: ModelConfig, batch: int, cache_len: int):
@@ -95,14 +102,9 @@ def init_decoder_cache(cfg: ModelConfig, batch: int, cache_len: int):
     dt = layers.dtype_of(cfg)
     if cfg.mla is not None:
         m = cfg.mla
-        cache = {
-            "ckv": jnp.zeros((batch, cache_len, m.kv_lora_rank), dt),
-            "krope": jnp.zeros((batch, cache_len, m.qk_rope_dim), dt),
-        }
-        specs = {
-            "ckv": ("batch", "seq_shard", None),
-            "krope": ("batch", "seq_shard", None),
-        }
+        # one row [c_kv, k_rope] per token: the decode kernel's key and value
+        cache = {"latent": jnp.zeros((batch, cache_len, m.kv_lora_rank + m.qk_rope_dim), dt)}
+        specs = {"latent": ("batch", "seq_shard", None)}
         return cache, specs
     kv_div = _kv_heads_shardable(cfg)
     seq_name = "seq" if kv_div else "seq_shard"

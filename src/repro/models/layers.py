@@ -7,6 +7,7 @@ bf16 weights/activations, fp32 norms/softmax/rope.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -55,18 +56,60 @@ def layernorm(x: jnp.ndarray, p: Params, eps: float = 1e-5) -> jnp.ndarray:
 
 
 # -- rotary / sinusoidal positions ------------------------------------------
-def rope_frequencies(dim: int, theta: float) -> jnp.ndarray:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def rope_frequencies(dim: int, theta: float, scaling=None) -> jnp.ndarray:
+    """Inverse frequencies (dim/2,); under YaRN `scaling` (a YarnConfig)
+    the low frequencies are interpolated by its factor and the high ones
+    kept, with a linear ramp between the dims that make beta_slow and
+    beta_fast rotations over the original context (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``)."""
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return extra
+
+    def correction_dim(rotations):
+        return (dim * math.log(scaling.original_max_position / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp                                  # 1: extrapolate, 0: interpolate
+    return extra / scaling.factor * (1.0 - keep) + extra * keep
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, hd); positions: (S,) or (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature term: 0.1 * mscale * ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_cos_scale(scaling) -> float:
+    """The factor YaRN applies to cos and sin (1 where mscale equals
+    mscale_all_dim, as in DeepSeek-V2-Lite)."""
+    if scaling is None:
+        return 1.0
+    return (yarn_mscale(scaling.factor, scaling.mscale)
+            / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float, *,
+               scaling=None, interleaved: bool = False) -> jnp.ndarray:
+    """x: (..., S, H, hd); positions: (S,) or (..., S). Rotate-half RoPE;
+    ``interleaved`` first gathers the even then the odd dims, as
+    DeepSeek-V2's ``apply_rotary_pos_emb`` does, so adjacent pairs rotate
+    together (the output stays in the gathered order)."""
     hd = x.shape[-1]
-    freqs = rope_frequencies(hd, theta)                      # (hd/2,)
+    freqs = rope_frequencies(hd, theta, scaling)             # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
-    cos = jnp.cos(angles)[..., None, :]                      # broadcast over heads
-    sin = jnp.sin(angles)[..., None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    m = rope_cos_scale(scaling)
+    cos = (jnp.cos(angles) * m)[..., None, :]                # broadcast over heads
+    sin = (jnp.sin(angles) * m)[..., None, :]
+    xf = x.astype(jnp.float32)
+    if interleaved:
+        x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    else:
+        x1, x2 = jnp.split(xf, 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
 

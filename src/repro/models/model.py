@@ -12,11 +12,21 @@ API:
     prefill(params, batch)                      -> (last_logits, cache)
     decode_step(params, token, cache, pos)      -> (logits, new_cache)
     init_cache(batch, cache_len)                -> (cache, logical_specs)
+
+``prefill`` and ``decode_step`` take ``with_stats=True`` to return a third
+value, the step's routing counts summed over its expert layers, int32
+(2 + B,): expert rows computed (padding included), (token, choice) pairs of
+each batch row (the same for every row), then each row's pairs on the
+experts held here; zeros for a model without routed experts.
+
+A decoder (dense, moe, vlm) keeps its layers in scanned stacks by name:
+``params["layers"]``, after ``params["dense_layers"]`` for a moe config
+with ``first_dense_layers``. Its cache is keyed the same way.
 """
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 
 import jax
@@ -83,9 +93,11 @@ class Model:
         )
 
         if cfg.family in ("dense", "moe", "vlm"):
-            params["layers"], specs["layers"] = _stack_init(
-                lambda k: blocks.init_decoder_layer(k, cfg), keys[2], cfg.n_layers
-            )
+            for name, n, dense in self._decoder_stacks():
+                params[name], specs[name] = _stack_init(
+                    lambda k, dense=dense: blocks.init_decoder_layer(k, cfg, dense),
+                    keys[4] if dense else keys[2], n,
+                )
             if cfg.family == "vlm":
                 params["patch_proj"] = layers.dense_init(
                     keys[3], (cfg.d_model, cfg.d_model), cfg.d_model, dt
@@ -138,6 +150,14 @@ class Model:
     def abstract_params(self):
         return jax.eval_shape(self.init, jax.random.PRNGKey(0))
 
+    def _decoder_stacks(self) -> List[Tuple[str, int, bool]]:
+        """(params key, layers, dense MLP) of each scanned decoder stack: a
+        moe config's leading dense layers first, then the rest."""
+        cfg = self.cfg
+        n = cfg.first_dense_layers if cfg.family == "moe" else 0
+        rest = [("layers", cfg.n_layers - n, False)]
+        return [("dense_layers", n, True)] + rest if n else rest
+
     def _hybrid_groups(self) -> Tuple[int, int]:
         cfg = self.cfg
         PG = cfg.shared_attn_every
@@ -167,12 +187,14 @@ class Model:
         positions = jnp.arange(S)
 
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(carry, lp):
-                hh, aux = carry
-                hh, a, _ = blocks.decoder_layer(lp, hh, cfg, positions)
-                return (hh, aux + a), None
+            aux = jnp.float32(0.0)
+            for name, _, dense in self._decoder_stacks():
+                def body(carry, lp, dense=dense):
+                    hh, aux = carry
+                    hh, a, _, _ = blocks.decoder_layer(lp, hh, cfg, positions, dense)
+                    return (hh, aux + a), None
 
-            (h, aux), _ = self._scan(body, (h, jnp.float32(0.0)), params["layers"])
+                (h, aux), _ = self._scan(body, (h, aux), params[name])
         elif cfg.family == "ssm":
             def body(carry, lp):
                 hh, _ = blocks.ssm_layer(lp, carry[0], cfg)
@@ -185,7 +207,7 @@ class Model:
 
             def group(carry, glp):
                 hh, aux = carry
-                hh, a, _ = blocks.decoder_layer(shared, hh, cfg, positions)
+                hh, a, _, _ = blocks.decoder_layer(shared, hh, cfg, positions)
 
                 def inner(c, lp):
                     h2, _ = blocks.ssm_layer(lp, c, cfg)
@@ -276,19 +298,24 @@ class Model:
         return total, {"ce": ce, "aux": aux, "loss": total}
 
     # ============================================================== prefill
-    def prefill(self, params, batch) -> Tuple[jnp.ndarray, Any]:
-        """Run the full prompt, return (last-position logits (B, V), cache)."""
+    def prefill(self, params, batch, with_stats: bool = False):
+        """Run the full prompt, return (last-position logits (B, V), cache),
+        and the routing counts where `with_stats`."""
         cfg = self.cfg
         h = self._embed_inputs(params, batch)
         S = h.shape[1]
         positions = jnp.arange(S)
+        counts = self._no_counts(h.shape[0] * S)
 
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(hh, lp):
-                hh, _, kv = blocks.decoder_layer(lp, hh, cfg, positions)
-                return hh, self._pack_kv(kv)
+            cache = {}
+            for name, _, dense in self._decoder_stacks():
+                def body(hh, lp, dense=dense):
+                    hh, _, kv, c = blocks.decoder_layer(lp, hh, cfg, positions, dense)
+                    return hh, (self._pack_kv(kv), c)
 
-            h, cache = self._scan_prefill(body, h, params["layers"])
+                h, (cache[name], c) = self._scan_prefill(body, h, params[name])
+                counts = jax.tree.map(lambda a, b: a + b.sum(axis=0), counts, c)
         elif cfg.family == "ssm":
             def body(hh, lp):
                 hh, state = blocks.ssm_layer(lp, hh, cfg, return_state=True)
@@ -299,7 +326,7 @@ class Model:
             shared = params["shared"]
 
             def group(hh, glp):
-                hh, _, kv = blocks.decoder_layer(shared, hh, cfg, positions)
+                hh, _, kv, _ = blocks.decoder_layer(shared, hh, cfg, positions)
 
                 def inner(c, lp):
                     c, state = blocks.ssm_layer(lp, c, cfg, return_state=True)
@@ -325,22 +352,40 @@ class Model:
         norm = layers.layernorm if cfg.family == "encdec" else layers.rmsnorm
         h = norm(h, params["final_norm"], cfg.norm_eps)
         logits = self._logits(params, h[:, -1:])[:, 0]
+        if with_stats:
+            return logits, cache, self._routing_stats(counts, S)
         return logits, cache
 
     def _pack_kv(self, kv):
         if self.cfg.mla is not None:
-            return {"ckv": kv[0], "krope": kv[1]}
+            return {"latent": kv}
         return {"k": kv[0], "v": kv[1]}
+
+    @staticmethod
+    def _no_counts(tokens: int):
+        return jnp.zeros(tokens, jnp.int32), jnp.int32(0)
+
+    def _routing_stats(self, counts, S: int) -> jnp.ndarray:
+        """A step's routing counts (held choices per token, rows computed),
+        summed over its expert layers, as ``with_stats`` returns them."""
+        held, rows = counts
+        cfg = self.cfg
+        routed = sum(n for _, n, dense in self._decoder_stacks() if not dense) \
+            if cfg.family == "moe" else 0
+        pairs = S * cfg.moe.top_k * routed if routed else 0
+        return jnp.concatenate([jnp.stack([rows, jnp.int32(pairs)]),
+                                held.reshape(-1, S).sum(axis=1)])
 
     def _scan_prefill(self, body, h, stacked):
         return self._scan_ys(body, h, stacked)
 
     # =============================================================== decode
-    def decode_step(self, params, token: jnp.ndarray, cache: Any, pos: jnp.ndarray
-                    ) -> Tuple[jnp.ndarray, Any]:
+    def decode_step(self, params, token: jnp.ndarray, cache: Any, pos: jnp.ndarray,
+                    with_stats: bool = False):
         """token: (B, 1) int32; pos: scalar int32 (write position). Returns
-        (logits (B, V), new_cache)."""
+        (logits (B, V), new_cache), and the routing counts where `with_stats`."""
         cfg = self.cfg
+        counts = self._no_counts(token.shape[0])
         h = layers.embed(token, params["embed"])
         if cfg.family == "encdec":
             pe = layers.sinusoidal_positions(cache_len_of(cache), cfg.d_model)
@@ -351,12 +396,15 @@ class Model:
         h = partition.shard_act(h, "batch", "seq", None)
 
         if cfg.family in ("dense", "moe", "vlm"):
-            def body(hh, xs):
-                lp, lc = xs
-                hh, nc = blocks.decoder_layer_decode(lp, hh, lc, pos, cfg)
-                return hh, nc
+            new_cache = {}
+            for name, _, dense in self._decoder_stacks():
+                def body(hh, xs, dense=dense):
+                    lp, lc = xs
+                    hh, nc, c = blocks.decoder_layer_decode(lp, hh, lc, pos, cfg, dense)
+                    return hh, (nc, c)
 
-            h, new_cache = self._scan_ys(body, h, (params["layers"], cache))
+                h, (new_cache[name], c) = self._scan_ys(body, h, (params[name], cache[name]))
+                counts = jax.tree.map(lambda a, b: a + b.sum(axis=0), counts, c)
         elif cfg.family == "ssm":
             def body(hh, xs):
                 lp, st = xs
@@ -369,7 +417,7 @@ class Model:
 
             def group(hh, xs):
                 glp, gc = xs
-                hh, attn_nc = blocks.decoder_layer_decode(shared, hh, gc["attn"], pos, cfg)
+                hh, attn_nc, _ = blocks.decoder_layer_decode(shared, hh, gc["attn"], pos, cfg)
 
                 def inner(c, ys):
                     lp, st = ys
@@ -393,6 +441,8 @@ class Model:
         norm = layers.layernorm if cfg.family == "encdec" else layers.rmsnorm
         h = norm(h, params["final_norm"], cfg.norm_eps)
         logits = self._logits(params, h)[:, 0]
+        if with_stats:
+            return logits, new_cache, self._routing_stats(counts, 1)
         return logits, new_cache
 
     # ================================================================ cache
@@ -409,7 +459,9 @@ class Model:
         if cfg.family in ("dense", "moe", "vlm"):
             # cache_len counts TOTAL sequence slots (patches included for vlm)
             c, s = blocks.init_decoder_cache(cfg, batch, cache_len)
-            return stack(c, s, cfg.n_layers)
+            stacks = {name: stack(c, s, n) for name, n, _ in self._decoder_stacks()}
+            return ({k: v[0] for k, v in stacks.items()},
+                    {k: v[1] for k, v in stacks.items()})
         if cfg.family == "ssm":
             c, s = mamba2.init_decode_state(cfg, batch)
             c = {"conv": c["conv"], "ssm": c["ssm"]}

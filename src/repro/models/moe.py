@@ -10,6 +10,14 @@ Sharding: experts shard over `model` (EP) when divisible — XLA inserts the
 data->expert all-to-all at the gather. Otherwise (e.g. 60 experts on a 16-way
 axis) experts replicate and each expert's d_ff shards over `model` (TP-MoE).
 Capacity shards over the data axes either way.
+
+A ``dropless`` config (DeepSeek-V2) takes the other path,
+:func:`held_experts_ffn`: the layer holds only its chip's share of the routed
+experts (``first_held``, ``n_held``), routes over all ``n_experts``, and
+computes every (token, choice) that lands on a held expert — each held
+expert's rows are the step's tokens, the unchosen ones weighted 0, so no
+capacity can drop a token. Choices of experts held elsewhere add nothing
+here. Shared experts follow, gated (Qwen2-MoE) or not (DeepSeek).
 """
 from __future__ import annotations
 
@@ -28,11 +36,12 @@ def init_moe(key, cfg: ModelConfig):
     D = cfg.d_model
     dt = layers.dtype_of(cfg)
     ks = jax.random.split(key, 6)
+    E = m.held                      # the routed experts this layer holds
     params = {
         "router": (jax.random.normal(ks[0], (D, m.n_experts), jnp.float32) * D ** -0.5),
-        "wi": layers.dense_init(ks[1], (m.n_experts, D, m.d_ff_expert), D, dt),
-        "wg": layers.dense_init(ks[2], (m.n_experts, D, m.d_ff_expert), D, dt),
-        "wo": layers.dense_init(ks[3], (m.n_experts, m.d_ff_expert, D), m.d_ff_expert, dt),
+        "wi": layers.dense_init(ks[1], (E, D, m.d_ff_expert), D, dt),
+        "wg": layers.dense_init(ks[2], (E, D, m.d_ff_expert), D, dt),
+        "wo": layers.dense_init(ks[3], (E, m.d_ff_expert, D), m.d_ff_expert, dt),
     }
     specs = {
         "router": ("embed", None),
@@ -44,9 +53,55 @@ def init_moe(key, cfg: ModelConfig):
         sh, sh_specs = layers.init_swiglu(ks[4], D, m.d_ff_shared, dt)
         params["shared"] = sh
         specs["shared"] = sh_specs
-        params["shared_gate"] = layers.dense_init(ks[5], (D, 1), D, dt)
-        specs["shared_gate"] = ("embed", None)
+        if m.shared_gate:
+            params["shared_gate"] = layers.dense_init(ks[5], (D, 1), D, dt)
+            specs["shared_gate"] = ("embed", None)
     return params, specs
+
+
+def _shared(x, p, m: MoEConfig):
+    """The shared experts' output, through Qwen2-MoE's sigmoid gate where
+    the config has one."""
+    y = layers.swiglu(x, p["shared"])
+    if m.shared_gate:
+        gate = jax.nn.sigmoid(
+            jnp.einsum("...d,dg->...g", x, p["shared_gate"]).astype(jnp.float32)
+        ).astype(x.dtype)
+        y = gate * y
+    return y
+
+
+def _aux_loss(probs, topi, m: MoEConfig):
+    """Switch-style load-balance loss: mean router prob x top-1 share."""
+    me = probs.mean(axis=0)
+    ce = jax.nn.one_hot(topi[:, 0], m.n_experts, dtype=jnp.float32).mean(axis=0)
+    return m.n_experts * jnp.sum(me * ce)
+
+
+def held_experts_ffn(x2d: jnp.ndarray, p, m: MoEConfig):
+    """Dropless routed experts of one chip's share. x2d (T, D) ->
+    (y (T, D), aux, (held int32 (T,): each token's choices of a held
+    expert, rows int32: expert rows computed))."""
+    T, D = x2d.shape
+    E = m.held
+    with jax.named_scope("moe_dispatch"):
+        topw, topi, probs = route(x2d, p["router"], m)
+        local = topi - m.first_held                               # (T, k)
+        held = (local >= 0) & (local < E)
+        # weight of each held expert for each token, 0 where not chosen
+        w = jnp.einsum("tk,tke->te", jnp.where(held, topw, 0.0),
+                       jax.nn.one_hot(local, E, dtype=jnp.float32))
+    with jax.named_scope("moe_experts"):
+        # every held expert over the step's T tokens: rows bounded by the
+        # step, never by a capacity
+        h = jnp.einsum("td,edf->etf", x2d, p["wi"])
+        g = jnp.einsum("td,edf->etf", x2d, p["wg"])
+        h = h * jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype)
+        ye = jnp.einsum("etf,efd->etd", h, p["wo"])
+    with jax.named_scope("moe_combine"):
+        y = jnp.einsum("etd,te->td", ye, w.astype(ye.dtype))
+    counts = (held.sum(axis=1, dtype=jnp.int32), jnp.int32(E * T))
+    return y, _aux_loss(probs, topi, m), counts
 
 
 def _capacity(n_tokens: int, m: MoEConfig) -> int:
@@ -176,10 +231,25 @@ def _moe_ffn_shard_map(x: jnp.ndarray, p, cfg: ModelConfig):
 
 def moe_ffn(x: jnp.ndarray, p, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x: (B, S, D) -> (out (B,S,D), aux load-balance loss scalar)."""
+    y, aux, _ = moe_layer(x, p, cfg)
+    return y, aux
+
+
+def moe_layer(x: jnp.ndarray, p, cfg: ModelConfig):
+    """x: (B, S, D) -> (out (B,S,D), aux loss, routing counts (held int32
+    (B*S,): each token's choices of an expert held here, rows int32: expert
+    rows computed, padding included))."""
     m = cfg.moe
     B, S, D = x.shape
     T = B * S
     x2d = x.reshape(T, D)
+    if m.dropless:
+        y, aux, counts = held_experts_ffn(x2d, p, m)
+        y = y.reshape(B, S, D)
+        if m.n_shared_experts:
+            y = y + _shared(x, p, m)
+        return y, aux, counts
+    assert m.held == m.n_experts, "a share of the experts needs the dropless path"
 
     ctx = partition.current()
     if (
@@ -191,11 +261,9 @@ def moe_ffn(x: jnp.ndarray, p, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarr
     ):
         y, aux = _moe_ffn_shard_map(x, p, cfg)
         if m.n_shared_experts:
-            gate = jax.nn.sigmoid(
-                jnp.einsum("bsd,dg->bsg", x, p["shared_gate"]).astype(jnp.float32)
-            ).astype(x.dtype)
-            y = y + gate * layers.swiglu(x, p["shared"])
-        return y, aux
+            y = y + _shared(x, p, m)
+        n_model = ctx.mesh.shape.get("model", 1)
+        return y, aux, _capacity_counts(T, m, _capacity(T // n_model, m) * n_model)
 
     topw, topi, probs = route(x2d, p["router"], m)
     gather_idx, combine_w, C, assign_slot = build_dispatch(topi, topw, T, m)
@@ -230,14 +298,10 @@ def moe_ffn(x: jnp.ndarray, p, cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarr
     y = partition.shard_act(y, "batch", "seq", None)
 
     if m.n_shared_experts:
-        gate = jax.nn.sigmoid(
-            jnp.einsum("bsd,dg->bsg", x, p["shared_gate"]).astype(jnp.float32)
-        ).astype(x.dtype)
-        y = y + gate * layers.swiglu(x, p["shared"])
+        y = y + _shared(x, p, m)
+    return y, _aux_loss(probs, topi, m), _capacity_counts(T, m, C)
 
-    # Switch-style load-balance aux loss
-    me = probs.mean(axis=0)                                  # mean router prob per expert
-    one_hot_top1 = jax.nn.one_hot(topi[:, 0], m.n_experts, dtype=jnp.float32)
-    ce = one_hot_top1.mean(axis=0)                           # fraction routed (top-1)
-    aux = m.n_experts * jnp.sum(me * ce)
-    return y, aux
+
+def _capacity_counts(T: int, m: MoEConfig, C: int):
+    """Routing counts of the capacity path: every expert is held here."""
+    return jnp.full((T,), m.top_k, jnp.int32), jnp.int32(m.n_experts * C)

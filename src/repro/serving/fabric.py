@@ -212,9 +212,23 @@ class ModelHost:
             kv_cache.cache_bytes(self.cfg, self.n_slots, max_len)
         )
 
-        self._prefill = jax.jit(model.prefill)
-        self._decode = jax.jit(model.decode_step, donate_argnums=(2,))
-        self._insert = jax.jit(kv_cache.insert_sequence)
+        # the served programs also return the step's routing counts, read
+        # back with its tokens in one transfer (_pick); named as the model's
+        # own methods, which the device trace's program names follow
+        def prefill(params, batch):
+            return model.prefill(params, batch, with_stats=True)
+
+        def decode_step(params, token, cache, pos):
+            return model.decode_step(params, token, cache, pos, with_stats=True)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode_step, donate_argnums=(2,))
+        self._pick = jax.jit(
+            lambda logits, counts: jnp.concatenate(
+                [jnp.argmax(logits, axis=-1).astype(jnp.int32), counts])
+        )
+        self._insert = jax.jit(kv_cache.insert_sequence, donate_argnums=(0,))
+        self._routed = self.cfg.moe is not None
 
         self._lock = threading.Lock()
         self.sessions: Dict[str, _SessionState] = {}
@@ -251,6 +265,18 @@ class ModelHost:
     def _count(self, name: str, n: int = 1) -> None:
         self.metrics.counter(name).inc(n)
 
+    def _count_routing(self, phase: str, stats, rows: List[int]) -> None:
+        """A step's routing counts (``Model.decode_step``'s `with_stats`),
+        read back with its tokens. Over the batch `rows` the step served:
+        ``serving.moe_assign_held.<phase>`` (token, choice) pairs routed to
+        the experts held here, ``serving.moe_assign_all.<phase>`` all pairs;
+        ``serving.moe_rows.<phase>`` the expert rows computed, idle slots'
+        and padding included."""
+        if self._routed:
+            self._count(f"serving.moe_assign_held.{phase}", int(stats[2:][rows].sum()))
+            self._count(f"serving.moe_assign_all.{phase}", int(stats[1]) * len(rows))
+            self._count(f"serving.moe_rows.{phase}", int(stats[0]))
+
     # -- session lifecycle -------------------------------------------------
     def prefill(self, session: str, tokens) -> int:
         """Open (or rebuild) `session` from its full token history; returns
@@ -279,8 +305,10 @@ class ModelHost:
                 (1, self.cfg.enc_seq, self.cfg.d_model), np.float32
             )
         with self.metrics.span("serving.prefill"):
-            logits, seq_cache = self._prefill(self.params, batch)
-            first = int(jnp.argmax(logits[0]))
+            logits, seq_cache, counts = self._prefill(self.params, batch)
+            out = np.asarray(self._pick(logits, counts))
+            first = int(out[0])
+        self._count_routing("prefill", out[1:], [0])
         if not self.batching:
             seq_cache = self._to_decode_cache(seq_cache)
         with self._lock:
@@ -319,8 +347,10 @@ class ModelHost:
             with self._lock:  # per-request baseline: one kernel per request
                 tok = jnp.asarray([[st.last]], jnp.int32)
                 pos = jnp.asarray([st.pos], jnp.int32)
-                logits, st.cache = self._decode(self.params, tok, st.cache, pos)
-                nxt = int(jnp.argmax(logits[0]))
+                logits, st.cache, counts = self._decode(self.params, tok, st.cache, pos)
+                out = np.asarray(self._pick(logits, counts))
+                nxt = int(out[0])
+                self._count_routing("decode", out[1:], [0])
                 st.pos += 1
         with self._lock:
             st.last = nxt
@@ -348,19 +378,26 @@ class ModelHost:
 
         Per step, ``serving.kv_blocks_read`` counts the K/V blocks the decode
         kernel reads per layer (each slot's, up to its position) and
-        ``serving.kv_blocks_cached`` the blocks the cache holds.
+        ``serving.kv_blocks_cached`` the blocks the cache holds; over MLA's
+        latent cache ``serving.mla_positions_read.decode`` counts the
+        positions it reads per layer (each slot's position + 1). The routing
+        counts come back with the tokens (`_count_routing`).
         """
         with self._lock, self.metrics.span("serving.step"):
             bk = self.block_k
             self._count("serving.kv_blocks_read", int(((self.slot_pos + bk) // bk).sum()))
             self._count("serving.kv_blocks_cached", self.n_slots * -(-self.max_len // bk))
+            if self.cfg.mla is not None:
+                self._count("serving.mla_positions_read.decode", int((self.slot_pos + 1).sum()))
             tok = self.slot_last[:, None].copy()
             pos_vec = jnp.asarray(self.slot_pos)
-            logits, self.cache = self._decode(
+            logits, self.cache, counts = self._decode(
                 self.params, jnp.asarray(tok), self.cache, pos_vec
             )
             with self.metrics.span("serving.readback"):
-                nt = np.asarray(jnp.argmax(logits, axis=-1))
+                packed = np.asarray(self._pick(logits, counts))
+            nt = packed[: self.n_slots]
+            self._count_routing("decode", packed[self.n_slots:], slots)
             out = {}
             for s in slots:
                 self.slot_last[s] = int(nt[s])

@@ -130,6 +130,43 @@ def test_decode_kernel_matches_ref(case, dtype, key):
     )
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("B,S,H,dk,dv,pos", [
+    (4, 400, 8, 288, 256, None),          # minicpm3's latent: 256 + 32 rope
+    (3, 300, 16, 576, 512, [0, 127, 299]),  # deepseek-v2's: 512 + 64 rope, 16 heads
+])
+def test_decode_kernel_values_from_key_rows(B, S, H, dk, dv, pos, dtype, key):
+    """MLA's latent decode: no value cache, the values are the first dv
+    columns of each key row, read once with it."""
+    ks = jax.random.split(key, 2)
+    q, kc = _rand(ks[0], (B, 1, H, dk), dtype), _rand(ks[1], (B, S, 1, dk), dtype)
+    pos = _edges(S, 1, dk) if pos is None else jnp.asarray(pos, jnp.int32)
+    out = decode_attention_pallas(q, kc, None, pos, dv=dv, interpret=True)
+    expected = ref.decode_attention_reference(q, kc, kc[..., :dv], pos)
+    assert out.shape == (B, 1, H, dv)
+    np.testing.assert_allclose(
+        out.astype(jnp.float32), expected.astype(jnp.float32),
+        rtol=TOL[dtype], atol=TOL[dtype],
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("Sq,hd,dv", [(64, 192, 128), (100, 96, 64)])
+def test_flash_value_width_differs(Sq, hd, dv, dtype, key):
+    """MLA's prefill: q and k of qk_nope + qk_rope, v of v_head_dim
+    (deepseek-v2: 192 and 128; minicpm3: 96 and 64)."""
+    ks = jax.random.split(key, 3)
+    q, k = _rand(ks[0], (1, Sq, 4, hd), dtype), _rand(ks[1], (1, Sq, 4, hd), dtype)
+    v = _rand(ks[2], (1, Sq, 4, dv), dtype)
+    out = flash_attention_pallas(q, k, v, causal=True, interpret=True)
+    expected = ref.mha_reference(q, k, v, causal=True)
+    assert out.shape == (1, Sq, 4, dv)
+    np.testing.assert_allclose(
+        out.astype(jnp.float32), expected.astype(jnp.float32),
+        rtol=TOL[dtype], atol=TOL[dtype],
+    )
+
+
 def test_causality_property(key):
     """Changing future keys/values must not change past outputs."""
     ks = jax.random.split(key, 4)

@@ -4,9 +4,11 @@ Nothing runs: each program is lowered and compiled for a described (not
 attached) v5e device, which raises what the chip's compiler would raise —
 block shapes off the (8, 128) tiling, too much VMEM, a program that does not
 fit. ``tpu_custom_call`` in the compiled text shows the Pallas kernel is in
-the program. Widths are qwen1.5-0.5b's (16 heads of 64) and mamba2-2.7b's
-(80 SSD heads of 64, state 128, chunk 256).
+the program. Widths are qwen1.5-0.5b's (16 heads of 64), mamba2-2.7b's
+(80 SSD heads of 64, state 128, chunk 256) and deepseek-v2-lite's (MLA: 16
+heads, one 576-wide latent head in decode, q/k 192 and v 128 in prefill).
 """
+import dataclasses
 import os
 import re
 
@@ -194,3 +196,38 @@ def test_served_decode_step_reads_cache_in_one_kernel(one_chip, monkeypatch):
         assert " copy(" not in line and " copy-done(" not in line, line[:160]
 
     assert compiled.memory_analysis().temp_size_in_bytes <= 6.04e9
+
+
+def test_deepseek_v2_lite_share_compiles_for_v5e(one_chip, monkeypatch):
+    """deepseek-v2-lite at published widths and one chip's share of an
+    8-way expert-parallel deployment (8 of 64 routed experts held), served
+    at 16 slots x 8704: the decode step runs the latent decode kernel (one
+    head, dk 576, dv 512) in each of its two layer stacks (the dense layer,
+    the expert layers) and fits the chip's 16 GiB with the weights and the
+    cache; prefill at 8192 runs flash attention at q/k 192 and v 128."""
+    monkeypatch.setattr(attn_ops, "_default_impl", lambda: "pallas")
+    cfg = get_config("deepseek-v2-lite")
+    cfg = cfg.with_(moe=dataclasses.replace(cfg.moe, n_held=8))
+    model = Model(cfg)
+    on_chip = lambda s: _spec(one_chip, s.shape, s.dtype)  # noqa: E731
+    params = jax.tree.map(on_chip, model.abstract_params())
+    cache = jax.tree.map(on_chip, jax.eval_shape(lambda: model.init_cache(16, 8704)[0]))
+
+    def decode_step(params, token, cache, pos):
+        return model.decode_step(params, token, cache, pos, with_stats=True)
+
+    compiled = jax.jit(decode_step, donate_argnums=(2,)).lower(
+        params, _spec(one_chip, (16, 1), jnp.int32), cache,
+        _spec(one_chip, (16,), jnp.int32),
+    ).compile()
+    kernel = 'custom_call_target="tpu_custom_call"'
+    assert compiled.as_text().count(kernel) == 2
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 10e9          # 6.22 GB weights + 4.33 GB cache
+    assert mem.peak_memory_in_bytes < 0.95 * 16 * 2**30
+
+    def prefill(params, batch):
+        return model.prefill(params, batch, with_stats=True)
+
+    text = _compiled_text(prefill, params, {"tokens": _spec(one_chip, (1, 8192), jnp.int32)})
+    assert text.count(kernel) == 2
