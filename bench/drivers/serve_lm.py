@@ -251,11 +251,33 @@ class Driver:
             t += 0.25
         return peak
 
+    def gap_notes(self) -> List[str]:
+        """Where the gaps between tokens lie: quantiles of all of them, and
+        how many hold another request's prefill (its first token received
+        inside the gap)."""
+        done = [r for r in self.recs if r.done]
+        firsts = np.sort([r.times[0] for r in done])
+        gaps, held = [], []
+        for r in done:
+            for a, b in zip(r.times, r.times[1:]):
+                gaps.append(b - a)
+                held.append(np.searchsorted(firsts, b, "right") > np.searchsorted(firsts, a, "right"))
+        if not gaps:
+            return []
+        gaps, held = np.asarray(gaps), np.asarray(held)
+        qs = (50, 80, 90, 93, 94, 95, 96, 97, 99, 100)
+        plain = gaps[~held] if (~held).any() else gaps
+        return ["itl quantiles " + " ".join(f"p{q}={np.percentile(gaps, q):.6f}" for q in qs)
+                + f" of {gaps.size} gaps",
+                f"itl gaps holding a prefill: {int(held.sum())}, median "
+                f"{np.median(gaps[held]) if held.any() else 0:.6f} s; the others' p95 "
+                f"{np.percentile(plain, 95):.6f} s, p99 {np.percentile(plain, 99):.6f} s"]
+
     def notes(self) -> List[str]:
         late = self.lateness or [0.0]
         occ = self.occupancy()
         held = self.slots * self.max_len
-        return [f"generator lateness: median {np.median(late):.6f} s, "
+        return self.gap_notes() + [f"generator lateness: median {np.median(late):.6f} s, "
                 f"max {np.max(late):.6f} s over {len(late)} arrivals",
                 f"drain: {self.drained_at - self.window_end:.3f} s after the window closed",
                 f"sessions in flight: peak {occ['sessions']} of {self.slots} slots; KV "

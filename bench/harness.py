@@ -199,13 +199,31 @@ class CompileCounter:
 
 
 # ---------------------------------------------------------------- tracing
-class Tracer(threading.Thread):
-    """Profiles `duration` seconds starting at host monotonic time `start_at`,
-    from a thread of its own, into `log_dir`."""
+def trace_offset(requests: Optional[List[Any]], seconds: float, dur: float) -> float:
+    """Where the traced slice starts, in seconds after the window opens.
 
-    def __init__(self, log_dir: str, start_at: float, duration: float):
+    With a schedule (`requests`, each with its `due` time): the first due
+    time at or after the middle, ``(seconds - dur) / 2``, among those that
+    leave room for the whole slice before `seconds`; failing that the last
+    that does. A closed loop (`requests` None), or a schedule with no room,
+    takes the middle. Only the schedule decides, so a program of any speed
+    is traced over the same seconds, and the slice opens as a request
+    arrives: its prefill and first decode steps lie inside it.
+    """
+    mid = (seconds - dur) / 2
+    fits = sorted(r.due for r in requests or () if r.due + dur <= seconds)
+    if not fits:
+        return mid
+    return next((d for d in fits if d >= mid), fits[-1])
+
+
+class Tracer(threading.Thread):
+    """Profiles `duration` seconds, from a thread of its own into `log_dir`,
+    starting `offset` seconds after `run`'s window opens."""
+
+    def __init__(self, log_dir: str, run: Run, offset: float, duration: float):
         super().__init__(name="bench-tracer", daemon=True)
-        self.log_dir, self.start_at, self.duration = log_dir, start_at, duration
+        self.log_dir, self.timed, self.offset, self.duration = log_dir, run, offset, duration
         self.mark_ns = 0
         self.t0 = self.t1 = 0.0
         self.error: Optional[BaseException] = None
@@ -216,7 +234,10 @@ class Tracer(threading.Thread):
         from .trace import MARK
 
         try:
-            time.sleep(max(0.0, self.start_at - time.monotonic()))
+            # the driver sets run.window as its window opens
+            while not self.timed.window[0]:
+                time.sleep(0.001)
+            time.sleep(max(0.0, self.timed.window[0] + self.offset - time.monotonic()))
             opts = jax.profiler.ProfileOptions()
             opts.python_tracer_level = 0
             opts.host_tracer_level = 1

@@ -7,9 +7,10 @@ or data from the seed, the cell's own shapes warmed), measures for
 ``--seconds``, checks what the timed path produced against the plain
 reference, and prints one JSON object as the last line of standard output.
 With ``--trace 0`` it reports the cell's end-to-end metrics; with
-``--trace 1`` it profiles a few seconds from the middle of the window and
-reports the per-layer metrics. Without a TPU, or with fewer chips than the
-cell asks for, it exits non-zero and prints no result.
+``--trace 1`` it profiles a few seconds of the window, from the first request
+due at or after its middle, and reports the per-layer metrics. Without a
+TPU, or with fewer chips than the cell asks for, it exits non-zero and
+prints no result.
 """
 from __future__ import annotations
 
@@ -93,7 +94,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     if trace:
         log_dir = tempfile.mkdtemp(prefix="bench-trace-")
         dur = min(TRACE_SECONDS, seconds / 2)
-        tracer = harness.Tracer(log_dir, time.monotonic() + (seconds - dur) / 2, dur)
+        tracer = harness.Tracer(log_dir, run, harness.trace_offset(drv.requests, seconds, dur),
+                                dur)
         tracer.start()
     run.counters_before = drv.service.metrics.snapshot()
     counter.active = True
@@ -116,7 +118,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     metrics = {}
     breakdown = None
     if trace:
-        run.trace = tr.load(tr.find_xplane(log_dir), tracer.mark_ns, tracer.t0, tracer.t1)
+        plane = "/device:TPU:0" if dev.platform == "tpu" else tr.CPU_PLANE
+        run.trace = tr.load(tr.find_xplane(log_dir), tracer.mark_ns, tracer.t0, tracer.t1,
+                            device=plane)
         shutil.rmtree(log_dir, ignore_errors=True)
         device["busy_s"] = run.trace.busy_s()
         device["window_s"] = run.trace.window_s

@@ -28,7 +28,7 @@ def test_cell_runs_and_is_correct(tiny_root, interpret_kernels, cell, trace):
         bm = json.load(f)
     wanted = {m["name"] for m in bm["end_to_end"] if cell in m.get("workloads", [cell])}
     if trace:
-        assert {"busy_s", "window_s"} <= set(res["device"])
+        assert 0 < res["device"]["busy_s"] <= res["device"]["window_s"]
         assert "breakdown" in res
         host_read = {m["name"] for m in bm["per_layer"] if cell in m["workloads"]
                      and m["source"] != "device_trace"}

@@ -82,7 +82,7 @@ def test_span_metrics_read_in_a_traced_run(tiny_root, interpret_kernels, monkeyp
         if c != cell:
             continue
         if name.startswith("idle_unexplained_pct."):
-            # the CPU trace holds no device operation to be idle between
-            assert name not in res["metrics"]
+            # a share of the idle time between the CPU's operations
+            assert 0 <= res["metrics"][name]["value"] <= 100, name
         else:
             assert res["metrics"][name]["value"] > 0, name

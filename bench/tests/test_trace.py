@@ -1,7 +1,9 @@
 """The trace reduction, on a small trace recorded on a TPU v5e: a 2-layer
 full-width qwen1.5-0.5b running two prefills (128 and 256 tokens), two slot
-inserts and three batched decode steps over 4 slots."""
+inserts and three batched decode steps over 4 slots. And where in the window
+the traced slice is placed."""
 import os
+from types import SimpleNamespace
 
 import pytest
 
@@ -66,8 +68,6 @@ def test_breakdown_lists(trace):
 def test_readers_on_recorded_trace(trace):
     """The per-layer readers on the recorded programs: shares of a peak in
     (0, 100], decode work given to the steps that served it."""
-    from types import SimpleNamespace
-
     from bench import readers
     from bench.harness import Run, TaskRec
     from bench.peaks import peaks_for
@@ -97,3 +97,77 @@ def test_readers_on_recorded_trace(trace):
     for read in (readers.step_mfu_decode, readers.decode_attention_roofline):
         v = read(run)
         assert v is not None and 0.0 < v <= 100.0, (read.__name__, v)
+
+
+# ---------------------------------------------------------------- placement
+def _schedule(traffic, seconds):
+    """The requests a driver plans for the committed mix `traffic`, or None
+    for a closed loop."""
+    from bench import traffic as gen
+    from bench.harness import BENCH_DIR, load_json
+
+    mix = load_json(os.path.join(BENCH_DIR, "traffic", traffic + ".json"))
+    plan = gen.sessions if mix["kind"] == "sessions" else gen.tasks
+    return plan(mix, 2**33 + 7, seconds)
+
+
+@pytest.mark.parametrize("seconds", [51.0, 3.0])
+@pytest.mark.parametrize("traffic", ["chat", "doc", "frames", "short"])
+def test_slice_starts_at_a_due_request(traffic, seconds):
+    """The slice opens as a request is due, the first at or after the
+    window's middle, and lies wholly inside the window."""
+    from bench.harness import trace_offset
+    from bench.run import TRACE_SECONDS
+
+    dur = min(TRACE_SECONDS, seconds / 2)
+    mid = (seconds - dur) / 2
+    requests = _schedule(traffic, seconds)
+    at = trace_offset(requests, seconds, dur)
+    assert 0.0 <= at and at + dur <= seconds
+    if requests is None:
+        assert at == mid
+        return
+    assert at in {r.due for r in requests}
+    fits = [r.due for r in requests if r.due + dur <= seconds]
+    assert not [d for d in fits if mid <= d < at]           # none passed over
+    assert at >= mid or not [d for d in fits if d >= mid]
+
+
+@pytest.mark.parametrize("requests", [None, [], [SimpleNamespace(due=50.0)]])
+def test_slice_without_a_fitting_request_takes_the_middle(requests):
+    from bench.harness import trace_offset
+
+    assert trace_offset(requests, 51.0, 3.0) == 24.0
+
+
+def test_slice_falls_back_to_the_last_request_that_fits():
+    from bench.harness import trace_offset
+
+    reqs = [SimpleNamespace(due=d) for d in (1.0, 10.0, 49.0)]
+    assert trace_offset(reqs, 51.0, 3.0) == 10.0
+
+
+@pytest.mark.parametrize("step_s,old_empty", [(0.005, True), (0.030, True), (0.130, False)])
+def test_doc_slice_holds_a_live_session_at_any_speed(step_s, old_empty):
+    """serve.v2lite.doc's schedule against a program taking `step_s` a token
+    after a 0.5 s prefill: the clock's slice [24, 27] is empty once a token
+    takes under ~35 ms; the slice placed by the schedule holds the prefill
+    and first decode step of the session due at its start."""
+    from bench.harness import trace_offset
+    from bench.run import TRACE_SECONDS
+
+    seconds, prefill = 51.0, 0.5
+    requests = _schedule("doc", seconds)
+    live = [(r.due, r.due + prefill + r.out_len * step_s) for r in requests]
+
+    def busy(a, b):
+        return [s for s in live if s[0] < b and s[1] > a]
+
+    dur = min(TRACE_SECONDS, seconds / 2)
+    old = (seconds - dur) / 2
+    assert (old, old + dur) == (24.0, 27.0)
+    assert (not busy(old, old + dur)) == old_empty
+    at = trace_offset(requests, seconds, dur)
+    assert at == pytest.approx(28.34, abs=0.01)
+    assert busy(at, at + dur)
+    assert at + prefill + step_s <= at + dur

@@ -19,6 +19,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
 MARK = "bench_clock_mark"
+CPU_PLANE = "/host:CPU"
 _FINGERPRINT = re.compile(r"\(\d+\)$")
 _OP_NAME = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)?\s*=")
 _SHAPE = re.compile(r"=\s*\(?([a-z0-9]+)\[([0-9,]*)\]")
@@ -152,7 +153,10 @@ def load(path: str, mark_mono_ns: int, t0: float, t1: float,
          device: str = "/device:TPU:0", mark: str = MARK) -> Trace:
     """Read `path` and return device `device`'s events between t0 and t1
     (host monotonic seconds). `mark_mono_ns` is the monotonic time taken
-    inside the trace annotation named `mark`."""
+    inside the trace annotation named `mark`. On the CPU, where the
+    benchmark's own tests run, `device` is ``CPU_PLANE``: the ops are those
+    that XLA's CPU runtime runs on the host's threads, and no program is
+    named."""
     from jax.profiler import ProfileData
 
     pd = ProfileData.from_file(path)
@@ -173,7 +177,19 @@ def load(path: str, mark_mono_ns: int, t0: float, t1: float,
 
     tr = Trace(t0=t0, t1=t1)
     for plane in pd.planes:
-        if plane.name == device:
+        if plane.name == CPU_PLANE:
+            for line in plane.lines:
+                events = list(line.events)
+                if device == CPU_PLANE:
+                    # XLA's CPU runtime closes each op it runs with "end: <op>"
+                    ended = {e.name[len("end: "):] for e in events if e.name.startswith("end: ")}
+                    tr.ops += [Op(e.name[:160], sec(e.start_ns), e.duration_ns * 1e-9,
+                                  kind=e.name.split(".")[0])
+                               for e in events if e.name in ended]
+                for e in events:
+                    if e.duration_ns > 0 and e.name not in _HOST_SKIP and e.name != mark:
+                        tr.host.append(Event(e.name, sec(e.start_ns), e.duration_ns * 1e-9))
+        elif plane.name == device:
             for line in plane.lines:
                 if line.name == "XLA Modules":
                     for e in line.events:
@@ -184,11 +200,6 @@ def load(path: str, mark_mono_ns: int, t0: float, t1: float,
                         kind, kernel, dtype, shape = _parse_op(e.name)
                         tr.ops.append(Op(e.name[:160], sec(e.start_ns), e.duration_ns * 1e-9,
                                          kind=kind, kernel=kernel, dtype=dtype, shape=shape))
-        elif plane.name == "/host:CPU":
-            for line in plane.lines:
-                for e in line.events:
-                    if e.duration_ns > 0 and e.name not in _HOST_SKIP and e.name != mark:
-                        tr.host.append(Event(e.name, sec(e.start_ns), e.duration_ns * 1e-9))
     tr.modules.sort(key=lambda m: m.start)
     _assign_modules(tr)
     tr.modules = [m for m in tr.modules if m.start + m.dur > t0 and m.start < t1]
